@@ -217,6 +217,9 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

@@ -793,11 +793,6 @@ def run_memplan_ab(*, width: float = 0.25, buckets=(64, 128),
         # batch vs on at its capped batch
         rows = {side: svc.measure_engine_memory(big)
                 for side, svc in (("off", svc_off), ("on", svc_on))}
-        if any("temp_bytes" not in r for r in rows.values()):
-            raise SystemExit(
-                "backend exposes no memory_analysis(); the --memplan "
-                "reduction gate cannot run here"
-            )
         reduction = 1.0 - (rows["on"]["temp_bytes"]
                            / max(rows["off"]["temp_bytes"], 1))
         if verbose:
@@ -1269,6 +1264,9 @@ def main(argv=None):
                     help="precisions the --memplan parity matrix covers "
                          "(must include 'f32')")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.memplan:
         return run_memplan_ab(width=args.width,
                               buckets=tuple(args.buckets),

@@ -87,7 +87,15 @@ class FCNEngine:
 
     # -- parameters ----------------------------------------------------------
     def init_params(self, key: jax.Array) -> Dict[str, Dict[str, jax.Array]]:
+        """He-normal convs; the last conv of each residual branch (a
+        ``res add`` word) is scaled by 1/sqrt(#branches), as Fixup/SkipInit
+        do, so the residual stream's variance stays within 2x of its input
+        instead of doubling per block.  Unscaled, a random ResNet-50's
+        logits reach std ~20 and any rounding (BFP, bf16) is amplified
+        through its 16 blocks."""
         params: Dict[str, Dict[str, jax.Array]] = {}
+        n_branches = sum(1 for idx in self.program.weight_bindings
+                         if self.program.words[idx].res_op == ResOp.ADD)
         for idx, name in self.program.weight_bindings.items():
             mc = self.program.words[idx]
             spec = self.program.layer_specs[idx]
@@ -99,6 +107,8 @@ class FCNEngine:
                     p = {"w": _he_init(k1, (k, k, 1, cout), k * k)}
                 else:
                     p = {"w": _he_init(k1, (k, k, cin, cout), k * k * cin)}
+                if mc.res_op == ResOp.ADD:
+                    p["w"] = p["w"] / np.sqrt(n_branches)
                 if spec.bias:
                     p["b"] = jnp.zeros((cout,), jnp.float32)
                 if spec.bn:

@@ -12,6 +12,11 @@ Tiling: grid (M/bm, N/bn, K/bk), K innermost so the f32 accumulator tile
 lives in a VMEM scratch across the K sweep.  `bk` must be a multiple of
 the BFP block size so exponent tiles align with mantissa tiles.
 
+Exponent layout: one slab per K tile, ``(K/bk, rows, bk/bs)``.  A block
+``(1, bm, bk/bs)`` then spans the slab's full last dimension, which the
+TPU compiler requires of any block narrower than 128 lanes (the flat
+``(rows, K/bs)`` layout is refused as soon as K spans more than one tile).
+
 VMEM budget per step (defaults bm=bn=256, bk=512, bs=32):
     A mantissa  256*512   int8   = 128 KiB     (x2 for pipeline ping-pong)
     B mantissa  512*256   int8   = 128 KiB
@@ -32,9 +37,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _bfp_matmul_kernel(
     ma_ref,      # (bm, bk)   int8/int16 mantissas of A
-    ea_ref,      # (bm, bk//bs) int32 block exponents of A
+    ea_ref,      # (1, bm, bk//bs) int32 block exponents of A
     mb_ref,      # (bk, bn)   mantissas of B
-    eb_ref,      # (bn, bk//bs) int32 block exponents of B (N-major layout)
+    eb_ref,      # (1, bn, bk//bs) int32 block exponents of B (N-major)
     o_ref,       # (bm, bn)   f32 out
     acc_ref,     # (bm, bn)   f32 VMEM scratch
     *,
@@ -51,12 +56,14 @@ def _bfp_matmul_kernel(
     # (exact power-of-two via exponent-field bitcast — see core.bfp.exp2i)
     from repro.core.bfp import exp2i
 
-    ea = jnp.repeat(ea_ref[...], block_size, axis=1)            # (bm, bk)
+    ea = jnp.repeat(ea_ref[0], block_size, axis=1)              # (bm, bk)
     a = ma_ref[...].astype(jnp.float32) * exp2i(ea - mantissa_bits)
-    eb = jnp.repeat(eb_ref[...], block_size, axis=1)            # (bn, bk)
+    eb = jnp.repeat(eb_ref[0], block_size, axis=1)              # (bn, bk)
     b = mb_ref[...].astype(jnp.float32) * exp2i(eb - mantissa_bits).T
-    # MXU contraction with f32 (wide) accumulation:
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # MXU contraction with f32 (wide) accumulation; HIGHEST keeps the
+    # 11-bit signed mantissas exact (a bf16 pass would round them)
+    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -91,6 +98,8 @@ def bfp_matmul_quantized(
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
     assert bk % block_size == 0
     ebk = bk // block_size
+    # (rows, K/bs) -> (K/bk, rows, bk/bs): one exponent slab per K tile
+    slabs = lambda e: e.reshape(e.shape[0], K // bk, ebk).transpose(1, 0, 2)
 
     return pl.pallas_call(
         functools.partial(
@@ -101,12 +110,12 @@ def bfp_matmul_quantized(
         grid=(M // bm, N // bn, K // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, ebk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((1, bm, ebk), lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn, ebk), lambda i, j, k: (j, k)),
+            pl.BlockSpec((1, bn, ebk), lambda i, j, k: (k, j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(ma, ea, mb, eb)
+    )(ma, slabs(ea), mb, slabs(eb))

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bfp as bfp_lib
-from repro.kernels import default_interpret
+import repro.kernels
 
 from .kernel import bfp_matmul_quantized
 
@@ -58,7 +58,7 @@ def bfp_matmul(
     ``interpret=None`` derives from the backend (compiled on TPU,
     interpreted elsewhere — see repro.kernels.default_interpret)."""
     if interpret is None:
-        interpret = default_interpret()
+        interpret = repro.kernels.default_interpret()
     M, K = a.shape
     _, N = b.shape
     qa = bfp_lib.quantize(

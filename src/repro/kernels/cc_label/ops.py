@@ -47,7 +47,7 @@ def cc_label_pallas(
     valid_mask: Optional[jax.Array] = None,
     *,
     th: int = 32,
-    tw: int = 32,
+    tw: int = 128,
     interpret: bool | None = None,
     return_stats: bool = False,
 ):
@@ -58,10 +58,12 @@ def cc_label_pallas(
     ``max_iters`` bounds the PHASE-2 merge rounds per image (phase 1
     always reaches the tile-local fixpoint); with ``return_stats`` the
     result is ``(labels, iters, converged)`` where ``iters`` counts
-    merge rounds and ``converged`` is per-image.  Planes that don't
-    divide into (th, tw) tiles are zero-padded for phase 1 only — label
-    values always index the ORIGINAL plane, and padding can never grow
-    or merge components (padded pixels are background)."""
+    merge rounds and ``converged`` is per-image.  A plane no larger than
+    a tile is one tile (whole-plane blocks are always TPU-legal); larger
+    planes are zero-padded to (th, tw) multiples for phase 1 only — keep
+    ``th`` a multiple of 8 and ``tw`` of 128 on TPU.  Label values always
+    index the ORIGINAL plane, and padding can never grow or merge
+    components (padded pixels are background)."""
     unbatched = score.ndim == 2
     if unbatched:
         score = score[None]
@@ -78,10 +80,12 @@ def cc_label_pallas(
     # -- phase 1: tile-local fixpoint in VMEM ------------------------------
     bh, bw = min(th, H), min(tw, W)
     ph, pw = (-H) % bh, (-W) % bw
-    pad = lambda a: (jnp.pad(a, ((0, 0), (0, ph), (0, pw)) + ((0, 0),) *
-                             (a.ndim - 3)) if ph or pw else a)
+    pad = lambda a, lead=1: (jnp.pad(a, ((0, 0),) * lead + ((0, ph), (0, pw)))
+                             if ph or pw else a)
     local = local_spread_converge(
-        pad(init), pad(pos.astype(jnp.int32)), pad(lnk.astype(jnp.int32)),
+        pad(init), pad(pos.astype(jnp.int32)),
+        # direction-major (N, 8, H, W): each direction is one 2-D tile
+        pad(jnp.moveaxis(lnk.astype(jnp.int32), -1, 1), 2),
         th=bh, tw=bw, interpret=interpret,
     )[:, :H, :W]
 

@@ -14,16 +14,23 @@ separate pass) — one launch covers contraction, output transform, bias,
 and activation, so the optimized engine issues a single dispatch per
 fused conv+bias+ReLU microcode word.
 
+Layout: position-major.  V is (36, P, Cin) and the output (16, P, Cout),
+so every per-position slice ``v_ref[t]`` / ``o_ref[r]`` is a plain 2-D
+(sublane, lane) tile: no in-kernel transpose or reshape, which the TPU
+compiler refuses for these shapes.
+
 Grid: (P/bp, Cout/bn, Cin/bk) with Cin innermost; the (36, bp, bn) f32
-accumulator lives in VMEM scratch across the Cin sweep.
+accumulator lives in VMEM scratch across the Cin sweep.  The flush is the
+output transform written out as static sums over the 36 accumulator
+slices with Aᵀ's coefficients (zeros skipped, ±1 as add/sub).
 
 VMEM per step (bp=128, bn=128, bk=128):
-    V tile   128*36*128*4  = 2.25 MiB   (x2 ping-pong)
-    U tile   36*128*128*4  = 2.25 MiB
+    V tile   36*128*128*4  = 2.25 MiB   (x2 ping-pong)
+    U tile   36*128*128*4  = 2.25 MiB   (x2 ping-pong)
     acc      36*128*128*4  = 2.25 MiB
-    out      128*16*128*4  = 1.00 MiB
-  ~10 MiB with double buffering — inside a v5e-class core budget; tests
-  sweep smaller blocks too.
+    out      16*128*128*4  = 1.00 MiB   (x2 ping-pong)
+  ~13.3 MiB plus the flush's 24 row sums (1.5 MiB): inside the v5e
+  default scoped VMEM limit (16 MiB).
 """
 from __future__ import annotations
 
@@ -35,49 +42,60 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.winograd import AT
-from repro.kernels import default_interpret
+import repro.kernels
 
 
-def _winograd_mm_kernel(at_ref, v_ref, u_ref, b_ref, o_ref, acc_ref, *,
-                        relu: bool):
-    """at: (4, 6) Aᵀ; v: (bp, 36, bk); u: (36, bk, bn); b: (1, bn);
-    o: (bp, 16, bn)."""
+def _lincomb(coeffs, terms):
+    """sum(c * t) with static coefficients: zeros skipped, +-1 as add/sub."""
+    out = None
+    for c, t in zip(coeffs, terms):
+        c = float(c)
+        if c == 0.0:
+            continue
+        if c == -1.0:
+            out = -t if out is None else out - t
+            continue
+        term = t if c == 1.0 else t * c
+        out = term if out is None else out + term
+    return out
+
+
+def _winograd_mm_kernel(v_ref, u_ref, b_ref, o_ref, acc_ref, *, relu: bool):
+    """v: (36, bp, bk); u: (36, bk, bn); b: (1, bn); o: (16, bp, bn)."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    v = v_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    # 36 independent MXU contractions, batched over the position axis
-    acc_ref[...] += jax.lax.dot_general(
-        jnp.swapaxes(v, 0, 1),            # (36, bp, bk)
-        u,                                # (36, bk, bn)
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                     # (36, bp, bn)
+    # 36 independent MXU contractions, one per tile position
+    for t in range(36):
+        acc_ref[t] += jnp.dot(
+            v_ref[t].astype(jnp.float32), u_ref[t].astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
-        acc = acc_ref[...]                # (36, bp, bn)
-        bp, bn = acc.shape[1], acc.shape[2]
-        at = at_ref[...]                  # (4, 6)
-        m = acc.reshape(6, 6, bp, bn)
-        # Y = Aᵀ M A over the two 6-axes (VPU work, fused with the flush)
-        y = jnp.einsum("ij,jkpn,lk->ilpn", at, m, at)    # (4, 4, bp, bn)
-        y = y.reshape(16, bp, bn).transpose(1, 0, 2)
-        y = y + b_ref[...][None]          # (bp, 16, bn) + (1, 1, bn)
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        o_ref[...] = y
+        # Y = Aᵀ M A with M[j, c] = acc[6j + c]: rows first (R = Aᵀ M),
+        # then columns (Y = R A), all static VPU sums
+        rows = [[_lincomb(AT[i], [acc_ref[6 * j + c] for j in range(6)])
+                 for c in range(6)] for i in range(4)]
+        bias = b_ref[...]                 # (1, bn)
+        for i in range(4):
+            for l in range(4):
+                y = _lincomb(AT[l], rows[i]) + bias
+                if relu:
+                    y = jnp.maximum(y, 0.0)
+                o_ref[4 * i + l] = y
 
 
 @functools.partial(
     jax.jit, static_argnames=("bp", "bn", "bk", "relu", "interpret")
 )
 def winograd_tile_matmul(
-    v: jax.Array,          # (P, 36, Cin)  transformed input tiles
+    v: jax.Array,          # (36, P, Cin)  transformed input tiles
     u: jax.Array,          # (36, Cin, Cout) transformed weights (G W Gᵀ)
     b: jax.Array | None = None,            # (Cout,) fused bias
     *,
@@ -87,13 +105,13 @@ def winograd_tile_matmul(
     relu: bool = False,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Returns (P, 16, Cout) output tiles (4x4 row-major per tile), with
+    """Returns (16, P, Cout) output tiles (4x4 row-major per tile), with
     the bias add and optional ReLU fused into the output-transform flush
     (``interpret=None`` derives from the backend — see
     repro.kernels.default_interpret)."""
     if interpret is None:
-        interpret = default_interpret()
-    P, t36, K = v.shape
+        interpret = repro.kernels.default_interpret()
+    t36, P, K = v.shape
     _, _, N = u.shape
     assert t36 == 36
     bp = min(bp, P)
@@ -106,13 +124,12 @@ def winograd_tile_matmul(
         functools.partial(_winograd_mm_kernel, relu=relu),
         grid=(P // bp, N // bn, K // bk),
         in_specs=[
-            pl.BlockSpec((4, 6), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((bp, 36, bk), lambda i, j, k: (i, 0, k)),
+            pl.BlockSpec((36, bp, bk), lambda i, j, k: (0, i, k)),
             pl.BlockSpec((36, bk, bn), lambda i, j, k: (0, k, j)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bp, 16, bn), lambda i, j, k: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((P, 16, N), jnp.float32),
+        out_specs=pl.BlockSpec((16, bp, bn), lambda i, j, k: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((16, P, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((36, bp, bn), jnp.float32)],
         interpret=interpret,
-    )(jnp.asarray(AT, jnp.float32), v, u, bias)
+    )(v, u, bias)

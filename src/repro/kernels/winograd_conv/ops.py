@@ -67,7 +67,7 @@ def winograd_conv2d(
     tiles = jnp.moveaxis(tiles, 2, 3)             # (N, th, tw, 6, 6, C)
     v = wg.transform_input(jnp.moveaxis(tiles, -1, -3))  # (N,th,tw,C,6,6)
     P = n * th * tw
-    v = v.reshape(P, cin, 36).transpose(0, 2, 1)  # (P, 36, Cin)
+    v = v.reshape(P, cin, 36).transpose(2, 0, 1)  # (36, P, Cin)
     u = wg.transform_weights(w.astype(jnp.float32))      # (6,6,Cin,Cout)
     u = u.reshape(36, cin, cout)
 
@@ -75,16 +75,16 @@ def winograd_conv2d(
     bp_ = min(bp, P)
     bn_ = min(bn, cout)
     bk_ = min(bk, cin)
-    vp = _pad_axis(_pad_axis(v, bp_, 0), bk_, 2)
+    vp = _pad_axis(_pad_axis(v, bp_, 1), bk_, 2)
     up = _pad_axis(_pad_axis(u, bk_, 1), bn_, 2)
     bias = None if b is None else _pad_axis(b.astype(jnp.float32), bn_, 0)
     y = winograd_tile_matmul(
         vp, up, bias, bp=bp_, bn=bn_, bk=bk_, relu=relu,
         interpret=interpret,
-    )[:P, :, :cout]                               # (P, 16, Cout)
+    )[:, :P, :cout]                               # (16, P, Cout)
 
-    y = y.reshape(n, th, tw, wg.TILE_OUT, wg.TILE_OUT, cout)
-    y = y.transpose(0, 1, 3, 2, 4, 5).reshape(
+    y = y.reshape(wg.TILE_OUT, wg.TILE_OUT, n, th, tw, cout)
+    y = y.transpose(2, 3, 0, 4, 1, 5).reshape(
         n, th * wg.TILE_OUT, tw * wg.TILE_OUT, cout
     )[:, :out_h, :out_w, :]
     return y

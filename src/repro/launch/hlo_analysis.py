@@ -13,7 +13,7 @@ ground truth the memplan peak-bytes prediction and the serve_bench
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -68,21 +68,15 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-def memory_stats(compiled: Any) -> Optional[Dict[str, int]]:
-    """Buffer-assignment sizes of a compiled executable, or None when
-    the backend exposes no ``memory_analysis()`` (older jaxlibs, some
-    plugin backends).  ``temp_bytes`` is the scratch the program needs
-    beyond arguments/outputs — the number liveness planning moves;
-    ``peak_bytes`` approximates total residency while a step runs."""
-    analysis_fn = getattr(compiled, "memory_analysis", None)
-    if analysis_fn is None:
-        return None
-    try:
-        mem = analysis_fn()
-    except Exception:
-        return None
+def memory_stats(compiled: Any) -> Dict[str, int]:
+    """Buffer-assignment sizes of a compiled executable.  ``temp_bytes``
+    is the scratch the program needs beyond arguments/outputs — the
+    number liveness planning moves; ``peak_bytes`` approximates total
+    residency while a step runs.  Raises when the backend reports no
+    analysis: a missing measurement must not read as zero bytes."""
+    mem = compiled.memory_analysis()
     if mem is None:
-        return None
+        raise RuntimeError("the backend reported no memory analysis")
 
     def _get(name: str) -> int:
         return int(getattr(mem, name, 0) or 0)
@@ -99,16 +93,12 @@ def memory_stats(compiled: Any) -> Optional[Dict[str, int]]:
     }
 
 
-def lowered_memory(fn: Any, *args: Any) -> Optional[Dict[str, int]]:
+def lowered_memory(fn: Any, *args: Any) -> Dict[str, int]:
     """AOT-lower ``fn`` (a jax.jit callable) at ``args`` (concrete
     arrays or ShapeDtypeStructs), compile, and return
     :func:`memory_stats` — one explicit compile, separate from any
-    call-path jit cache."""
-    try:
-        compiled = fn.lower(*args).compile()
-    except Exception:
-        return None
-    return memory_stats(compiled)
+    call-path jit cache.  Compile errors propagate."""
+    return memory_stats(fn.lower(*args).compile())
 
 
 def op_histogram(hlo_text: str, top: int = 15) -> List[Tuple[str, int]]:

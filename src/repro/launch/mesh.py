@@ -10,14 +10,10 @@ import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """Version-portable ``jax.make_mesh``: newer JAX wants explicit
-    ``axis_types`` (``jax.sharding.AxisType`` appeared after 0.4.x);
-    older JAX has neither the enum nor the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler, as shard_map and jit in_shardings expect)."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

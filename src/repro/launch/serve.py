@@ -54,13 +54,13 @@ paper's throughput tricks:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.batching import LatencyRecorder, MicroBatcher, round_batch
@@ -128,7 +128,18 @@ class STDService:
                  model: str = "pixellink",
                  memplan: bool = True,
                  activation_budget_bytes: Optional[int] = None,
-                 engine_cache_bytes: int = 0):
+                 engine_cache_bytes: int = 0,
+                 config: Optional[Any] = None,
+                 device: Optional[Any] = None):
+        """``config`` is the base ``STDConfig`` every bucket's model is
+        built from (``dataclasses.replace`` sets the plane and the
+        precision axis); without one the base is the reduced VGG-16
+        serving model at ``width``/``mode``/``memplan``, which a config
+        carries itself (so they are refused beside one).  ``device`` pins
+        the SingleDevice engines and their parameters to one device, and
+        with them each batch's inputs (one replica per chip behind
+        launch/router.Router)."""
+        from repro.core import BFPConfig
         from repro.models.fcn.heads import (
             DetectionModel, build_head, check_model,
         )
@@ -136,6 +147,10 @@ class STDService:
 
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
+        if config is not None and (width, mode, memplan) != (
+                0.25, "optimized", True):
+            raise ValueError("width/mode/memplan come from config when one "
+                             "is given; set them on the STDConfig")
         if postprocess not in ("host", "device"):
             raise ValueError(
                 f"postprocess must be 'host' or 'device', got {postprocess!r}"
@@ -161,6 +176,11 @@ class STDService:
         self.precision = check_precision(precision)
         self.plan: ExecutionPlan = plan if plan is not None else SingleDevice()
         self.planner = planner
+        if device is not None and (
+                not isinstance(self.plan, SingleDevice)
+                or tall_plan is not None or planner is not None):
+            raise ValueError("device pins SingleDevice engines only; a "
+                             "mesh plan places its own shards")
         m = plan_batch_multiple(self.plan)
         if tall_plan is not None:
             # tall_plan may be any plan type now, including data-sharded
@@ -184,7 +204,12 @@ class STDService:
         # activation footprints fit — a memory-heavy bucket compiles its
         # engines at a SMALLER batch (lower temp bytes), a light bucket
         # may batch above the fixed max_batch.  None = fixed max_batch.
-        self.memplan_enabled = bool(memplan)
+        base = config if config is not None else STDConfig(
+            backbone="vgg16", width=width, merge_ch=(16, 16, 8),
+            mode=mode, memplan=memplan,
+        )
+        self.config = base
+        self.memplan_enabled = bool(base.memplan)
         self.activation_budget_bytes = activation_budget_bytes
         self._bucket_caps: Dict[Tuple[int, int], int] = {}
         self.max_wait_ms = max_wait_ms
@@ -197,8 +222,7 @@ class STDService:
         self.inflight = inflight
         self._lock = threading.Lock()
         self._batcher: Optional[MicroBatcher] = None
-        self._width = width
-        self._mode = mode
+        self._mode = base.mode
         # the telemetry book every layer writes into: engine dispatch
         # walls (EngineFactory), full step walls (this service's
         # completion path), scheduler stage timings/gauges
@@ -207,22 +231,18 @@ class STDService:
 
         def make_model(hw, precision="f32", model="pixellink"):
             # "bfp" runs the paper's quantized datapath: BFP convs with
-            # FP16 data-pool storage, Pallas kernels where the backend
-            # compiles them (interpret-mode Pallas off the TPU would be
-            # orders of magnitude slower than XLA, so it stays off in
-            # serving — the kernels themselves are covered by tests).
-            # The model arg selects the detection head; one factory can
-            # serve several zoo models through the same LRU.
-            from repro.core import BFPConfig
-
+            # FP16 data-pool storage, and the Pallas kernels on TPU (they
+            # lower through Mosaic only; interpret-mode Pallas elsewhere
+            # would be orders of magnitude slower than XLA, so it stays
+            # off in serving — the kernels themselves are covered by
+            # tests).  The model arg selects the detection head; one
+            # factory can serve several zoo models through the same LRU.
             bfp = precision == "bfp"
-            return DetectionModel(STDConfig(
-                backbone="vgg16", width=width, image_size=hw,
-                merge_ch=(16, 16, 8), mode=mode,
-                bfp=BFPConfig() if bfp else None,
+            return DetectionModel(dataclasses.replace(
+                base, image_size=tuple(hw),
+                bfp=(base.bfp or BFPConfig()) if bfp else None,
                 storage_fp16=bfp,
-                use_pallas=bfp and jax.default_backend() in ("gpu", "tpu"),
-                memplan=memplan,
+                use_pallas=bfp and jax.default_backend() == "tpu",
             ), build_head(model, score_thr=score_thr,
                           link_thr=link_thr))
 
@@ -232,6 +252,7 @@ class STDService:
             capacity=engine_cache_capacity,
             book=self.book,
             engine_bytes_budget=engine_cache_bytes,
+            device=device,
         )
         if planner is not None:
             planner.bind_features(self._plan_features,
@@ -279,11 +300,8 @@ class STDService:
         if cap is None:
             from repro.core.memplan import admissible_batch
 
-            try:
-                per_image = self.factory.memplan(
-                    hw, self.precision, self.model_name).peak_bytes
-            except Exception:
-                per_image = 0            # plan failure must not stop serving
+            per_image = self.factory.memplan(
+                hw, self.precision, self.model_name).peak_bytes
             cap = admissible_batch(per_image, self.activation_budget_bytes,
                                    multiple=self._batch_multiple)
             self._bucket_caps[hw] = cap
@@ -384,7 +402,11 @@ class STDService:
                                   self.model_name)
         params = self.factory.params(hw, self.precision, self.model_name)
         t0 = time.perf_counter()
-        pending = fn(params, jnp.asarray(stack), jnp.asarray(valid_q))
+        # host arrays go straight to the engine's input shardings (a
+        # mesh plan's shards, or the device the committed params sit on);
+        # a default-device copy would be resharded by an extra compiled
+        # slice program on the first call
+        pending = fn(params, stack, valid_q)
         if self.postprocess_mode == "device":
             # labels are already valid-masked, so padding contributes no
             # components; coordinates live in label-map (quarter) space
@@ -562,12 +584,8 @@ class STDService:
                    f'model="{row["model"]}"')
             out[f"std_engine_planned_peak_bytes{{{lbl}}}"] = float(
                 row.get("planned_peak_bytes", 0))
-            if "temp_bytes" in row:
-                out[f"std_engine_temp_bytes{{{lbl}}}"] = float(
-                    row["temp_bytes"])
-            if "peak_bytes" in row:
-                out[f"std_engine_peak_bytes{{{lbl}}}"] = float(
-                    row["peak_bytes"])
+            out[f"std_engine_temp_bytes{{{lbl}}}"] = float(row["temp_bytes"])
+            out[f"std_engine_peak_bytes{{{lbl}}}"] = float(row["peak_bytes"])
         for hw, cap in sorted(self._bucket_caps.items()):
             out[f'std_bucket_batch_cap{{bucket="{hw[0]}x{hw[1]}"}}'] = \
                 float(cap)
@@ -741,6 +759,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.data.images import RequestStream
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     svc = STDService(width=args.width, mode=args.mode,
                      max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,

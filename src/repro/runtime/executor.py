@@ -84,11 +84,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.launch.batching import LRUCache
 from repro.models.fcn.heads import DEFAULT_MODEL, check_model
 from repro.runtime.collectives import halo_exchange
-from repro.runtime.sharding import (
-    fcn_activation_specs,
-    mesh_axis_sizes,
-    shard_map_compat,
-)
+from repro.runtime.sharding import fcn_activation_specs, mesh_axis_sizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,6 +272,7 @@ class EngineFactory:
         book: Any = None,
         cc_pallas: Any = None,
         engine_bytes_budget: int = 0,
+        device: Any = None,
     ):
         self.make_model = make_model
         # make_model generations: legacy (hw), precision-aware
@@ -294,6 +291,9 @@ class EngineFactory:
         self.score_thr = score_thr
         self.link_thr = link_thr
         self.book = book
+        # parameters committed to ``device`` pull every engine call that
+        # uses them onto it (one replica per chip); None = default device
+        self.device = device
         # Pallas tile-local CC kernel only beats the jnp while_loop where
         # it actually compiles (TPU Mosaic); elsewhere the interpreter
         # would be orders of magnitude slower than XLA
@@ -366,6 +366,8 @@ class EngineFactory:
                 p = (model_obj.init_params(jax.random.PRNGKey(0))
                      if precision == "f32"
                      else model_obj.normalize_weights(raw))
+                if self.device is not None:
+                    p = jax.device_put(p, self.device)
                 self._params.put((hw, precision, model), p)
             return p
 
@@ -426,7 +428,7 @@ class EngineFactory:
             "precision": precision, "model": model,
             "planned_peak_bytes": self.engine_weight_bytes(
                 hw, batch, precision, model),
-            **(stats or {}),
+            **stats,
         }
         self._mem_measured[key] = row
         self.stats.setdefault("engine_memory", []).append(row)
@@ -461,11 +463,8 @@ class EngineFactory:
              "plan": describe_plan(plan), "precision": precision,
              "model": model}
         )
-        try:
-            weight = self.engine_weight_bytes(hw, batch, precision, model)
-        except Exception:
-            weight = 0          # planning must never block serving
-        self._engines.put(key, fn, weight=weight)
+        self._engines.put(key, fn, weight=self.engine_weight_bytes(
+            hw, batch, precision, model))
         return fn
 
     def _timed(self, fn: Callable, hw, batch: int, kind: str,
@@ -483,6 +482,10 @@ class EngineFactory:
                                   model=model)
             return out
 
+        # AOT lowering stays available through the wrapper: a caller that
+        # compiles ``lower(...).compile()`` warms the same executable the
+        # timed calls then run
+        timed.lower = fn.lower
         return timed
 
     def _tail(self, model_obj, out, valid_q):
@@ -598,10 +601,10 @@ class EngineFactory:
             out = model_obj.apply(params, x)
             return self._tail(model_obj, out, valid_q)
 
-        return jax.jit(shard_map_compat(
-            shard, plan.mesh,
+        return jax.jit(jax.shard_map(
+            shard, mesh=plan.mesh,
             in_specs=(P(), specs["image"], P(plan.axis)),
-            out_specs=(*payload_specs, P(plan.axis)),
+            out_specs=(*payload_specs, P(plan.axis)), check_vma=False,
         ), donate_argnums=_donate_argnums())
 
     def _compile_row_band(self, hw, plan, precision: str = "f32",
@@ -650,15 +653,27 @@ class EngineFactory:
             out = band_model.apply(params, x, band_ctx=ctx)
             return tuple(out[n] for n, _ in maps)
 
-        sm = shard_map_compat(
-            shard, mesh,
+        sm = jax.shard_map(
+            shard, mesh=mesh,
             in_specs=(P(), specs["image"]),
-            out_specs=map_specs,
+            out_specs=map_specs, check_vma=False,
+        )
+
+        # the tail needs whole planes, so it runs in a second shard_map
+        # over the batch axis only (rows gathered; replicated without a
+        # batch axis) — a Pallas (Mosaic) CC kernel cannot be partitioned
+        # automatically by the compiler
+        n_out = len(getattr(head, "payload_ranks", (3,))) + 1
+        tail = jax.shard_map(
+            lambda valid_q, *m: self._tail(
+                model_obj, dict(zip((n for n, _ in maps), m)), valid_q),
+            mesh=mesh,
+            in_specs=(P(batch_axis),) * (len(maps) + 1),
+            out_specs=(P(batch_axis),) * n_out, check_vma=False,
         )
 
         def run(params, x, valid_q):
-            out = dict(zip((n for n, _ in maps), sm(params, x)))
-            return self._tail(model_obj, out, valid_q)
+            return tail(valid_q, *sm(params, x))
 
         return jax.jit(run, donate_argnums=_donate_argnums())
 

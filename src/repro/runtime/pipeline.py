@@ -195,11 +195,9 @@ def pipeline_apply(
         jax.tree_util.tree_map(lambda _: P(stage_axis), staged_params),
         P(),
     )
-    from repro.runtime.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return fn(staged_params, x)
 

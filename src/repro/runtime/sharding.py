@@ -31,24 +31,6 @@ def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, *,
-                     check: bool = False):
-    """Version-portable shard_map: ``jax.shard_map(check_vma=...)`` on
-    newer JAX, ``jax.experimental.shard_map.shard_map(check_rep=...)``
-    on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
-    )
-
-
 def batch_seq_spec(
     mesh: Mesh, batch: int, seq: Optional[int] = None
 ) -> P:

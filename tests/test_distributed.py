@@ -70,14 +70,13 @@ class TestCompressedCollectives:
             from functools import partial
             from repro.runtime.collectives import compressed_psum
             from repro.launch.mesh import make_mesh
-            from repro.runtime.sharding import shard_map_compat
             mesh = make_mesh((8,), ("data",))
             from jax.sharding import PartitionSpec as P
             x = jax.random.normal(jax.random.PRNGKey(0), (8, 4, 64))
-            f = shard_map_compat(
+            f = jax.shard_map(
                 lambda xs: compressed_psum(xs[0], "data", mantissa_bits=7),
                 mesh=mesh, in_specs=P("data"), out_specs=P(),
-                check=False,
+                check_vma=False,
             )
             got = f(x)
             want = jnp.sum(x, axis=0)

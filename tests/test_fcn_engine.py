@@ -1,6 +1,8 @@
 """FCN engine tests: assembler address/concat semantics, residual cache
 ops, backbone assembly, engine modes (reference/optimized/BFP), STD model
 end-to-end, CC postprocess vs union-find."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -134,6 +136,23 @@ class TestBackbones:
         out = eng(params, x)
         sizes = [out[t].shape[1] for t in taps]
         assert sizes == [16, 8, 4, 2]              # 1/4, 1/8, 1/16, 1/32
+
+    def test_resnet50_random_init_logits_stay_bounded(self):
+        """The residual-branch init scale (1/sqrt(#branches)) keeps a
+        random ResNet-50's logits O(1): std ~0.28 at width 0.125, ~14
+        without it.  The bfp-vs-f32 parity gate is held on these weights,
+        so a change here changes what that gate measures."""
+        from repro.configs.pixellink_std import RESNET50
+        from repro.models.fcn import PixelLinkModel
+
+        cfg = dataclasses.replace(RESNET50, width=0.125,
+                                  image_size=(64, 64), bfp=None,
+                                  storage_fp16=False)
+        m = PixelLinkModel(cfg)
+        params = m.init_params(jax.random.PRNGKey(0))
+        x = jax.random.uniform(jax.random.PRNGKey(1), (2, 64, 64, 3))
+        logits = m.apply(params, x)["logits"]
+        assert 0.01 < float(jnp.std(logits)) < 3.0
 
     @pytest.mark.parametrize("backbone", ["vgg16", "resnet50"])
     def test_std_model_end_to_end(self, backbone):

@@ -55,11 +55,11 @@ class TestHaloExchange2D:
         shards on the "data" axis."""
         out = run_sub("""
             import numpy as np
+            import jax
             import jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
             from repro.launch.mesh import make_mesh
             from repro.runtime.collectives import halo_exchange
-            from repro.runtime.sharding import shard_map_compat
 
             mesh = make_mesh((2, 4), ("data", "model"))
             # global (N=2, H=8, W=1, C=1): batch over "data", rows over
@@ -85,11 +85,12 @@ class TestHaloExchange2D:
             # several bands); axis_size both static and psum-derived
             for halo, axis_size in [(1, 4), (1, 0), (2, 4), (3, 4),
                                     (3, 0), (5, 4)]:
-                f = shard_map_compat(
+                f = jax.shard_map(
                     lambda a: halo_exchange(
                         a, "model", halo, axis=1, axis_size=axis_size),
-                    mesh, in_specs=P("data", "model", None, None),
+                    mesh=mesh, in_specs=P("data", "model", None, None),
                     out_specs=P("data", "model", None, None),
+                    check_vma=False,
                 )
                 got = np.asarray(f(jnp.asarray(x))).squeeze()
                 np.testing.assert_array_equal(

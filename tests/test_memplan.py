@@ -418,13 +418,12 @@ class TestServiceIntegration:
         assert snap[planned[0]] == float(row["planned_peak_bytes"])
         assert any(k.startswith("std_bucket_batch_cap{bucket=\"64x64\"")
                    for k in snap)
-        if "temp_bytes" in row:          # backend exposes memory_analysis
-            assert any(k.startswith("std_engine_temp_bytes") and lbl in k
-                       for k in snap)
-            # planned-vs-measured sanity: same order of magnitude (XLA
-            # fuses aggressively, so only a generous band is stable)
-            ratio = row["temp_bytes"] / row["planned_peak_bytes"]
-            assert 0.1 < ratio < 50.0
+        assert any(k.startswith("std_engine_temp_bytes") and lbl in k
+                   for k in snap)
+        # planned-vs-measured sanity: same order of magnitude (XLA
+        # fuses aggressively, so only a generous band is stable)
+        ratio = row["temp_bytes"] / row["planned_peak_bytes"]
+        assert 0.1 < ratio < 50.0
 
     def test_lifetime_sentinel_exceeds_any_program(self):
         assert _END > 10**6
