@@ -48,6 +48,25 @@ class BFPConfig:
     wide_accum: bool = True      # False reproduces the pre-Fig.7 failure
 
 
+def word_kind(mc: Microcode, spec) -> str:
+    """The datapath a microcode word drives, as its named scope says:
+    ``conv1x1``/``conv3x3`` (``conv<k>x<k>`` at stride 1),
+    ``conv_strided``, ``depthwise``, ``pool``, ``upsample``, or the
+    extended op (``add``, ``sigmoid``, ``identity``)."""
+    lt = LayerType(mc.layer_type)
+    if lt == LayerType.CONV:
+        if spec.table and spec.table.get("depthwise"):
+            return "depthwise"
+        if mc.stride_n > 1:
+            return "conv_strided"
+        return f"conv{mc.kernel_size}x{mc.kernel_size}"
+    if lt == LayerType.POOL:
+        return "pool"
+    if lt == LayerType.UPSAMPLE:
+        return "upsample"
+    return ExtOp(mc.ext_opcode).name.lower()
+
+
 def _he_init(key, shape, fan_in):
     return jax.random.normal(key, shape, jnp.float32) * np.sqrt(2.0 / fan_in)
 
@@ -180,33 +199,42 @@ class FCNEngine:
             from repro.kernels.bfp_matmul import ops as bops
 
             n, hh, ww, cin = x.shape
+            # the f32 views in and out of the kernel: scope bfp_matmul_io
+            with jax.named_scope("bfp_matmul_io"):
+                xm = x.astype(jnp.float32).reshape(-1, cin)
+                wm = w.astype(jnp.float32).reshape(cin, -1)
             y = bops.bfp_matmul(
-                x.astype(jnp.float32).reshape(-1, cin),
-                w.astype(jnp.float32).reshape(cin, -1),
+                xm, wm,
                 block_size=self.bfp.block_size,
                 mantissa_bits=self.bfp.mantissa_bits,
                 rounding=self.bfp.rounding,
-            ).reshape(n, hh, ww, -1)
+            )
+            with jax.named_scope("bfp_matmul_io"):
+                y = y.reshape(n, hh, ww, -1)
             return fuse.conv_epilogue(y, b, relu)
         if self.bfp is not None:
-            x = bfp_lib.roundtrip(
-                x.astype(jnp.float32),
-                block_size=self.bfp.block_size,
-                mantissa_bits=self.bfp.mantissa_bits,
-                axis=-1,
-                rounding=self.bfp.rounding,
-            )
-            # weights quantize in-call too (paper Fig. 4's normalization
-            # branch must hold whether or not the caller ran
-            # normalize_weights() offline — trunc rounding is idempotent,
-            # so pre-normalized weights pass through unchanged)
-            w = bfp_lib.roundtrip(
-                w.astype(jnp.float32),
-                block_size=self.bfp.block_size,
-                mantissa_bits=self.bfp.mantissa_bits,
-                axis=-2,                       # block along Cin (K dim)
-                rounding=self.bfp.rounding,
-            )
+            # Algorithm 1 on both operands: scope bfp_roundtrip (what the
+            # benchmark's quantize_share reads)
+            with jax.named_scope("bfp_roundtrip"):
+                x = bfp_lib.roundtrip(
+                    x.astype(jnp.float32),
+                    block_size=self.bfp.block_size,
+                    mantissa_bits=self.bfp.mantissa_bits,
+                    axis=-1,
+                    rounding=self.bfp.rounding,
+                )
+                # weights quantize in-call too (paper Fig. 4's
+                # normalization branch must hold whether or not the
+                # caller ran normalize_weights() offline — trunc rounding
+                # is idempotent, so pre-normalized weights pass through
+                # unchanged)
+                w = bfp_lib.roundtrip(
+                    w.astype(jnp.float32),
+                    block_size=self.bfp.block_size,
+                    mantissa_bits=self.bfp.mantissa_bits,
+                    axis=-2,                       # block along Cin (K dim)
+                    rounding=self.bfp.rounding,
+                )
         x = x.astype(jnp.float32)
         w = w.astype(jnp.float32)
         if depthwise:
@@ -348,78 +376,81 @@ class FCNEngine:
         indices = plan.schedule if plan is not None else range(len(prog.words))
         for idx in indices:
             mc = prog.words[idx]
-            wp = plan.word(idx) if plan is not None else None
             spec = prog.layer_specs[idx]
-            xin = read(mc.in_addr, mc.in_ch)
-            name = prog.weight_bindings.get(idx)
-            p = params.get(name, {}) if name else {}
-            lt = LayerType(mc.layer_type)
-            fused_relu = False
-            if lt == LayerType.CONV:
-                # conv+bias+ReLU fuse into one launch (optimized mode;
-                # eligibility is a plan fact when a memplan is bound, the
-                # per-call fuse.py check otherwise — the residual register
-                # reads the pre-activation value, so res words keep a
-                # separate ReLU either way)
-                eligible = (wp.fuse_relu if wp is not None
-                            else fuse.can_fuse_conv_epilogue(mc))
-                fused_relu = self.mode == "optimized" and eligible
-                y = self._spatial_banded(
-                    band_ctx, xin, mc.kernel_size, mc.stride_n,
-                    lambda xb: self._conv(xb, p, mc, spec,
-                                          transposed=transposed,
-                                          relu=fused_relu),
-                )
-            elif lt == LayerType.POOL:
-                y = self._spatial_banded(
-                    band_ctx, xin, 2 if mc.kernel == 0 else 3, mc.stride_n,
-                    lambda xb: self._pool(xb, mc, spec),
-                )
-            elif lt == LayerType.UPSAMPLE:
-                up_conv = (wp.fuse_upsample if wp is not None
-                           else spec.upsample_mode != "nearest")
-                y = self._spatial_banded(
-                    band_ctx, xin,
-                    3 if up_conv else 1, 1,
-                    lambda xb: self._upsample(xb, p, mc, spec,
-                                              decomposed=up_conv),
-                    out_scale=2,
-                )
-            else:
-                op = ExtOp(mc.ext_opcode)
-                if op == ExtOp.SIGMOID:
-                    y = jax.nn.sigmoid(xin)
-                elif op == ExtOp.ADD:
-                    y = xin + read(mc.ext_addr2, mc.in_ch)
-                elif op == ExtOp.IDENTITY:
-                    y = xin
-                else:
-                    raise NotImplementedError(
-                        f"FCN engine does not implement {op!r}; LM opcodes "
-                        f"run through build_stream_fn"
+            with jax.named_scope(f"w{idx:03d}.{word_kind(mc, spec)}"):
+                wp = plan.word(idx) if plan is not None else None
+                xin = read(mc.in_addr, mc.in_ch)
+                name = prog.weight_bindings.get(idx)
+                p = params.get(name, {}) if name else {}
+                lt = LayerType(mc.layer_type)
+                fused_relu = False
+                if lt == LayerType.CONV:
+                    # conv+bias+ReLU fuse into one launch (optimized
+                    # mode; eligibility is a plan fact when a memplan is
+                    # bound, the per-call fuse.py check otherwise — the
+                    # residual register reads the pre-activation value,
+                    # so res words keep a separate ReLU either way)
+                    eligible = (wp.fuse_relu if wp is not None
+                                else fuse.can_fuse_conv_epilogue(mc))
+                    fused_relu = self.mode == "optimized" and eligible
+                    y = self._spatial_banded(
+                        band_ctx, xin, mc.kernel_size, mc.stride_n,
+                        lambda xb: self._conv(xb, p, mc, spec,
+                                              transposed=transposed,
+                                              relu=fused_relu),
                     )
-            if mc.res_op == ResOp.CACHE:
-                cache = y
-            elif mc.res_op == ResOp.ADD:
-                assert cache is not None, "res add with empty cache register"
-                y = y + cache
-            if mc.relu and not fused_relu:
-                y = jax.nn.relu(y)
-            # write back to the data pool in storage precision (FP16 in the
-            # paper; f32 for the reference numerics)
-            y = y.astype(self.storage_dtype)
-            if wp is None or wp.store:
-                arena[mc.out_addr] = y
-                h, w, c = prog.addr_shapes[mc.out_addr]
-                extents[mc.out_addr] = h * w * c * STORAGE_BYTES
-            if wp is not None:
-                # drop buffers at their last use so the trace holds no
-                # reference past the plan's liveness range
-                for a in wp.free_after:
-                    arena.pop(a, None)
-                    extents.pop(a, None)
-                if wp.drop_cache:
-                    cache = None
+                elif lt == LayerType.POOL:
+                    y = self._spatial_banded(
+                        band_ctx, xin, 2 if mc.kernel == 0 else 3,
+                        mc.stride_n,
+                        lambda xb: self._pool(xb, mc, spec),
+                    )
+                elif lt == LayerType.UPSAMPLE:
+                    up_conv = (wp.fuse_upsample if wp is not None
+                               else spec.upsample_mode != "nearest")
+                    y = self._spatial_banded(
+                        band_ctx, xin,
+                        3 if up_conv else 1, 1,
+                        lambda xb: self._upsample(xb, p, mc, spec,
+                                                  decomposed=up_conv),
+                        out_scale=2,
+                    )
+                else:
+                    op = ExtOp(mc.ext_opcode)
+                    if op == ExtOp.SIGMOID:
+                        y = jax.nn.sigmoid(xin)
+                    elif op == ExtOp.ADD:
+                        y = xin + read(mc.ext_addr2, mc.in_ch)
+                    elif op == ExtOp.IDENTITY:
+                        y = xin
+                    else:
+                        raise NotImplementedError(
+                            f"FCN engine does not implement {op!r}; LM "
+                            f"opcodes run through build_stream_fn"
+                        )
+                if mc.res_op == ResOp.CACHE:
+                    cache = y
+                elif mc.res_op == ResOp.ADD:
+                    assert cache is not None, \
+                        "res add with empty cache register"
+                    y = y + cache
+                if mc.relu and not fused_relu:
+                    y = jax.nn.relu(y)
+                # write back to the data pool in storage precision (FP16
+                # in the paper; f32 for the reference numerics)
+                y = y.astype(self.storage_dtype)
+                if wp is None or wp.store:
+                    arena[mc.out_addr] = y
+                    h, w, c = prog.addr_shapes[mc.out_addr]
+                    extents[mc.out_addr] = h * w * c * STORAGE_BYTES
+                if wp is not None:
+                    # drop buffers at their last use so the trace holds no
+                    # reference past the plan's liveness range
+                    for a in wp.free_after:
+                        arena.pop(a, None)
+                        extents.pop(a, None)
+                    if wp.drop_cache:
+                        cache = None
 
         return {k: arena[a] for k, a in prog.outputs.items()}
 

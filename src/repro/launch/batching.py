@@ -38,12 +38,15 @@ handle), ``finalize_fn(key, raw) -> outputs`` materializes it, and
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, Hashable, List, Optional
+
+from repro.runtime.telemetry import span, unwatch_gc, watch_gc
 
 
 class QueueFull(RuntimeError):
@@ -216,6 +219,7 @@ class _Item:
     payload: Any
     future: Future
     t_submit: float
+    req: int = -1                 # request id, the spans' ``req``
 
 
 class MicroBatcher:
@@ -233,6 +237,12 @@ class MicroBatcher:
     holding), which bounds device memory while letting H2D/compute/D2H
     of consecutive batches overlap.  ``inflight=0`` finalizes inline in
     the dispatch thread — the fully serialized legacy path.
+
+    Every stage runs inside a ``runtime/telemetry.span`` keyed by the
+    request id (``req``, given at submit) and the batch id (``batch``,
+    given at formation): ``std.enqueue``, ``std.batch`` (a mark),
+    ``std.dispatch``, ``std.complete``, ``std.post``, and ``std.gc`` for
+    interpreter GC pauses while running (docs/serving.md "Spans").
     """
 
     def __init__(
@@ -303,6 +313,12 @@ class MicroBatcher:
         # and caller threads — every mutation holds _stats_lock (the
         # counters are read-modify-write, so the GIL alone loses updates)
         self._stats_lock = threading.Lock()
+        # ids: itertools.count's next() is atomic under the GIL
+        self._req_ids = itertools.count()
+        self._batch_ids = itertools.count()
+        # GC pause walls, appended by the process-wide gc hook while
+        # running and moved into the book by the dispatch stage
+        self._gc_pauses: deque = deque()
         self.stats: Dict[str, Any] = {
             "batches": [],            # {key, n, reason, queued_ms}
             "flush_full": 0,
@@ -351,6 +367,7 @@ class MicroBatcher:
         # wall accumulates across stop()/start() cycles because the busy
         # counters (and every other stat) do too
         self._t_start = time.perf_counter()
+        watch_gc(self._gc_pauses)
         self._sched_t.start()
         self._dispatch_t.start()
         if self._complete_t is not None:
@@ -368,6 +385,8 @@ class MicroBatcher:
         if self._complete_t is not None:
             self._complete_t.join()
         self._post_pool.shutdown(wait=True)
+        unwatch_gc(self._gc_pauses)
+        self._drain_gc()
         self._wall_s += time.perf_counter() - self._t_start
         with self._stats_lock:
             self.stats["stage_occupancy"] = {
@@ -414,12 +433,32 @@ class MicroBatcher:
             out["queue_depth"] = float(self._n_pending)
         return out
 
+    def _drain_gc(self) -> None:
+        """Move the GC pauses seen so far into the book's ``gc_pause_s``."""
+        while self._gc_pauses:
+            dt = self._gc_pauses.popleft()
+            if self.book is not None:
+                self.book.observe("gc_pause_s", dt)
+
     # -- request side ----------------------------------------------------------
-    def submit(self, key: Hashable, payload: Any) -> Future:
+    def request_id(self) -> int:
+        """A fresh request id, for a caller whose spans start before
+        :meth:`submit` (the service's preprocess)."""
+        return next(self._req_ids)
+
+    def submit(self, key: Hashable, payload: Any,
+               req: Optional[int] = None) -> Future:
         """Enqueue one request.  At ``max_pending`` queued items the
         admission policy applies: "reject" raises :class:`QueueFull`
         immediately (load shedding), "block" waits for the scheduler to
-        drain a batch (backpressure on the caller thread)."""
+        drain a batch (backpressure on the caller thread).  ``req`` is
+        the id from :meth:`request_id`; a fresh one is taken without."""
+        if req is None:
+            req = next(self._req_ids)
+        with span("std.enqueue", req=req, key=key):
+            return self._enqueue(key, payload, req)
+
+    def _enqueue(self, key: Hashable, payload: Any, req: int) -> Future:
         fut: Future = Future()
         with self._cond:
             if self._stop or not self._running:
@@ -436,7 +475,7 @@ class MicroBatcher:
                 self._cond.wait()
                 if self._stop or not self._running:
                     raise RuntimeError("MicroBatcher is not running")
-            item = _Item(key, payload, fut, self.clock())
+            item = _Item(key, payload, fut, self.clock(), req)
             self._pending.setdefault(key, deque()).append(item)
             self._n_pending += 1
             with self._stats_lock:
@@ -512,6 +551,14 @@ class MicroBatcher:
     def _sched_loop(self):
         while True:
             batch = self._next_batch()
+            if batch is not None:
+                key, reason, items = batch
+                bid = next(self._batch_ids)
+                with span("std.batch", batch=bid, first_req=items[0].req,
+                          n=len(items), reason=reason,
+                          queued_ms=(self.clock() - items[0].t_submit) * 1e3):
+                    pass
+                batch = (key, reason, items, bid)
             self._infer_q.put(batch)          # None = drained sentinel
             if batch is None:
                 return
@@ -528,7 +575,8 @@ class MicroBatcher:
                 if self._complete_t is not None:
                     self._done_q.put(None)
                 return
-            key, reason, items = got
+            key, reason, items, bid = got
+            self._drain_gc()
             with self._stats_lock:
                 self.stats[f"flush_{reason}"] += 1
                 self.stats["batch_items"] += len(items)
@@ -539,27 +587,27 @@ class MicroBatcher:
             if self.book is not None:
                 self.book.observe("mb_batch_occupancy",
                                   len(items) / self.max_batch)
-            t0 = time.perf_counter()
+            sp = span("std.dispatch", book=self.book, series="mb_dispatch_s",
+                      batch=bid, live=len(items))
             try:
-                raw = self.infer_fn(key, [it.payload for it in items])
+                with sp:
+                    raw = self.infer_fn(key, [it.payload for it in items])
             except Exception as e:
                 for it in items:
                     it.future.set_exception(e)
                 continue
             finally:
-                dt = time.perf_counter() - t0
                 with self._stats_lock:
-                    self.stats["dispatch_busy_s"] += dt
-                if self.book is not None:
-                    self.book.observe("mb_dispatch_s", dt)
+                    self.stats["dispatch_busy_s"] += sp.seconds
             with self._stats_lock:
                 self._in_flight += 1
                 if self._in_flight > self.stats["inflight_peak"]:
                     self.stats["inflight_peak"] = self._in_flight
             if self._complete_t is None:
-                self._complete_one(key, items, raw)
+                self._complete_one(key, items, raw, bid)
             else:
-                self._done_q.put((key, items, raw))   # bounded: backpressure
+                # bounded: backpressure
+                self._done_q.put((key, items, raw, bid))
 
     # -- completion stage ------------------------------------------------------
     def _complete_loop(self):
@@ -569,23 +617,22 @@ class MicroBatcher:
                 return
             self._complete_one(*got)
 
-    def _complete_one(self, key, items, raw):
-        t0 = time.perf_counter()
+    def _complete_one(self, key, items, raw, bid: int = -1):
+        sp = span("std.complete", book=self.book, series="mb_complete_s",
+                  batch=bid)
         try:
-            outs = raw if self.finalize_fn is None \
-                else self.finalize_fn(key, raw)
-            n_out = len(outs)
+            with sp:
+                outs = raw if self.finalize_fn is None \
+                    else self.finalize_fn(key, raw)
+                n_out = len(outs)
         except Exception as e:
             for it in items:
                 it.future.set_exception(e)
             return
         finally:
-            dt = time.perf_counter() - t0
             with self._stats_lock:
                 self._in_flight -= 1
-                self.stats["complete_busy_s"] += dt
-            if self.book is not None:
-                self.book.observe("mb_complete_s", dt)
+                self.stats["complete_busy_s"] += sp.seconds
         if n_out < len(items):
             # a finalize returning fewer outputs than live items would
             # silently strand the tail futures (zip stops early) and
@@ -607,17 +654,18 @@ class MicroBatcher:
             if self.post_fn is None:
                 self._resolve(it, out)
             else:
-                self._post_pool.submit(self._post_one, it, out)
+                self._post_pool.submit(self._post_one, it, out, bid)
 
-    def _post_one(self, item: _Item, out: Any):
-        t0 = time.perf_counter()
+    def _post_one(self, item: _Item, out: Any, bid: int = -1):
+        sp = span("std.post", req=item.req, batch=bid)
         try:
-            self._resolve(item, self.post_fn(item.payload, out))
+            with sp:
+                self._resolve(item, self.post_fn(item.payload, out))
         except Exception as e:
             item.future.set_exception(e)
         finally:
             with self._stats_lock:
-                self.stats["post_busy_s"] += time.perf_counter() - t0
+                self.stats["post_busy_s"] += sp.seconds
 
     def _resolve(self, item: _Item, result: Any):
         # sample lands BEFORE set_result, so anything observable through

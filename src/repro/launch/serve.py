@@ -76,7 +76,9 @@ from repro.runtime.executor import (
 )
 from repro.runtime.pipeline import HostPipeline
 from repro.runtime.planner import Planner, features_for_program
-from repro.runtime.telemetry import CostBook, prometheus_text
+from repro.runtime.telemetry import (
+    CostBook, current_span, prometheus_text, span,
+)
 
 MAX_WIDTH = 4096          # the paper's width limit
 
@@ -224,8 +226,8 @@ class STDService:
         self._batcher: Optional[MicroBatcher] = None
         self._mode = base.mode
         # the telemetry book every layer writes into: engine dispatch
-        # walls (EngineFactory), full step walls (this service's
-        # completion path), scheduler stage timings/gauges
+        # walls and full step walls (this service's dispatch and
+        # completion paths), scheduler stage timings/gauges
         # (MicroBatcher) — metrics_snapshot() exports it all
         self.book = book if book is not None else CostBook()
 
@@ -250,7 +252,6 @@ class STDService:
             make_model,
             score_thr=score_thr, link_thr=link_thr,
             capacity=engine_cache_capacity,
-            book=self.book,
             engine_bytes_budget=engine_cache_bytes,
             device=device,
         )
@@ -373,40 +374,53 @@ class STDService:
         pad[:h, :w] = img
         return pad, (h, w), transposed
 
-    def _dispatch(self, stack: np.ndarray,
-                  valid_hws: List[Tuple[int, int]]):
-        """Route + pad + submit one batch; returns the pending device
-        tuple — the head's ``(*payload, converged)`` on the
-        host-postprocess path (``(labels, converged)`` for the CC
+    def _dispatch(self, stack, valid_hws: List[Tuple[int, int]]):
+        """Route + pad + submit one batch (``stack``: a (B, H, W, 3)
+        array or a list of (H, W, 3) planes, stacked here); returns the
+        pending device tuple — the head's ``(*payload, converged)`` on
+        the host-postprocess path (``(labels, converged)`` for the CC
         heads), with the compact on-device ``(rows, counts)`` boxes
         appended on the device path — and the step-telemetry meta
         ``(hw, batch, kind, t0)`` the completion path hands to
         :meth:`_record_step`.  Nothing here blocks: the boxes fn is a
         jitted call on the pending labels, so it joins the same async
-        dispatch chain."""
-        hw = tuple(stack.shape[1:3])
-        n_live = len(valid_hws)
-        b = round_batch(n_live, self._bucket_cap(hw), self.batch_round)
-        plan = self._plan_for(hw, b)
-        m = plan_batch_multiple(plan)            # data-parallel divisibility
-        b = -(-b // m) * m
-        if b > n_live:
-            stack = np.concatenate(
-                [stack, np.zeros((b - n_live,) + stack.shape[1:],
-                                 stack.dtype)]
-            )
-        valid_q = np.zeros((b, 2), np.int32)
-        for i, (vh, vw) in enumerate(valid_hws):
-            valid_q[i] = (vh // 4, vw // 4)
+        dispatch chain.  Spans: ``std.dispatch.prepare`` (stack, pad,
+        valid extents), then ``std.dispatch.call`` (the engine call:
+        input transfer enqueued and the step launched); the enclosing
+        ``std.dispatch`` learns the padded batch and the plan."""
+        with span("std.dispatch.prepare", book=self.book,
+                  series="mb_prepare_s"):
+            if isinstance(stack, list):
+                stack = np.stack(stack)
+            hw = tuple(stack.shape[1:3])
+            n_live = len(valid_hws)
+            b = round_batch(n_live, self._bucket_cap(hw), self.batch_round)
+            plan = self._plan_for(hw, b)
+            m = plan_batch_multiple(plan)        # data-parallel divisibility
+            b = -(-b // m) * m
+            if b > n_live:
+                stack = np.concatenate(
+                    [stack, np.zeros((b - n_live,) + stack.shape[1:],
+                                     stack.dtype)]
+                )
+            valid_q = np.zeros((b, 2), np.int32)
+            for i, (vh, vw) in enumerate(valid_hws):
+                valid_q[i] = (vh // 4, vw // 4)
+        kind = plan_kind(plan)
+        outer = current_span()             # std.dispatch under the batcher
+        if outer is not None:
+            outer.note(padded=b, plan=kind)
         fn = self.factory.plan_fn(hw, b, plan, self.precision,
                                   self.model_name)
         params = self.factory.params(hw, self.precision, self.model_name)
-        t0 = time.perf_counter()
-        # host arrays go straight to the engine's input shardings (a
-        # mesh plan's shards, or the device the committed params sit on);
-        # a default-device copy would be resharded by an extra compiled
-        # slice program on the first call
-        pending = fn(params, stack, valid_q)
+        with span("std.dispatch.call", book=self.book, series=dict(
+                hw=hw, batch=b, kind=kind, stage="dispatch",
+                precision=self.precision, model=self.model_name)) as call:
+            # host arrays go straight to the engine's input shardings (a
+            # mesh plan's shards, or the device the committed params sit
+            # on); a default-device copy would be resharded by an extra
+            # compiled slice program on the first call
+            pending = fn(params, stack, valid_q)
         if self.postprocess_mode == "device":
             # labels are already valid-masked, so padding contributes no
             # components; coordinates live in label-map (quarter) space
@@ -414,7 +428,7 @@ class STDService:
             rows, counts = self.factory.boxes_fn(
                 hw, b, self.boxes_capacity)(pending[0])
             pending = (*pending, rows, counts)
-        return pending, (hw, b, plan_kind(plan), t0)
+        return pending, (hw, b, kind, call.t0)
 
     def _record_step(self, meta) -> None:
         """One materialized batch's dispatch-through-D2H wall into the
@@ -476,8 +490,18 @@ class STDService:
         wrong), or the head's per-image payload on the host path (the
         label map for the CC heads, a tuple of maps for multi-payload
         heads like EAST).  Records the ``stage="step"`` wall and the
-        non-convergence counter."""
+        non-convergence counter.  Spans: ``std.complete.wait`` (blocked
+        on the step, the one span that is a wait) and
+        ``std.complete.fetch`` (device-to-host copy and the split)."""
         pending, meta = raw
+        with span("std.complete.wait", book=self.book,
+                  series="mb_complete_wait_s"):
+            jax.block_until_ready(pending)
+        with span("std.complete.fetch", book=self.book,
+                  series="mb_complete_fetch_s"):
+            return self._fetch(pending, meta)
+
+    def _fetch(self, pending, meta) -> List[Any]:
         n_payload = self.head.n_payload
         if len(pending) == n_payload + 3:       # device (rows, counts)
             labels, converged, rows, counts = pending
@@ -515,21 +539,20 @@ class STDService:
         lands in the CostBook under ``stage="postprocess"`` keyed by
         the bucket shape and the head's decode kind (derived from the
         payload plane when ``bucket_hw`` isn't given — device-compact
-        rows carry no plane, so they require it)."""
-        t0 = time.perf_counter()
-        boxes, kind = self.head.decode(payload, valid_hw)
-        if bucket_hw is None:
-            plane = self.head.payload_plane(payload)
-            if plane is None:
-                raise ValueError(
-                    "device-compact payloads carry no plane shape; pass "
-                    "bucket_hw"
-                )
-            bucket_hw = (plane[0] * 4, plane[1] * 4)
-        self.book.record_step(tuple(bucket_hw), 1, kind,
-                              time.perf_counter() - t0,
-                              stage="postprocess",
-                              model=self.model_name)
+        rows carry no plane, so they require it).  Span:
+        ``std.post.decode``."""
+        with span("std.post.decode", book=self.book) as sp:
+            boxes, kind = self.head.decode(payload, valid_hw)
+            if bucket_hw is None:
+                plane = self.head.payload_plane(payload)
+                if plane is None:
+                    raise ValueError(
+                        "device-compact payloads carry no plane shape; "
+                        "pass bucket_hw"
+                    )
+                bucket_hw = (plane[0] * 4, plane[1] * 4)
+            sp.series = dict(hw=tuple(bucket_hw), batch=1, kind=kind,
+                             stage="postprocess", model=self.model_name)
         if transposed:                              # inverse transposition
             for b in boxes:
                 x0, y0, x1, y1 = b["box"]
@@ -660,8 +683,8 @@ class STDService:
         array (plus step-telemetry meta) without blocking — the
         completion stage materializes it, so the next bucket's batch
         dispatches while this one computes."""
-        stack = np.stack([p[0] for p in payloads])
-        return self._dispatch(stack, [p[1] for p in payloads])
+        return self._dispatch([p[0] for p in payloads],
+                              [p[1] for p in payloads])
 
     def _mb_finalize(self, key, raw):
         """Completion stage: block on the device result (D2H — the full
@@ -703,11 +726,15 @@ class STDService:
 
     def submit(self, img: np.ndarray) -> Future:
         """Async request: preprocess on the caller thread (the pipeline's
-        pre stage), then enqueue on the bucket's micro-batch."""
-        if self._batcher is None:
+        pre stage, span ``std.preprocess``), then enqueue on the bucket's
+        micro-batch under the same request id."""
+        batcher = self._batcher
+        if batcher is None:
             raise RuntimeError("call start_batched() first")
-        x, valid, tr = self.preprocess(img)
-        return self._batcher.submit(x.shape[:2], (x, valid, tr))
+        req = batcher.request_id()
+        with span("std.preprocess", req=req):
+            x, valid, tr = self.preprocess(img)
+        return batcher.submit(x.shape[:2], (x, valid, tr), req=req)
 
     def serve_batched(self, images: List[np.ndarray], *,
                       pre_workers: int = 4) -> List[List[Dict]]:

@@ -71,17 +71,17 @@ to XLA (:func:`_donate_argnums`).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import inspect
 import threading
-import time
 from typing import Any, Callable, Dict, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.launch.batching import LRUCache
+# the module, not its names: launch.batching imports runtime.telemetry,
+# whose package imports this module while batching is still loading
+from repro.launch import batching
 from repro.models.fcn.heads import DEFAULT_MODEL, check_model
 from repro.runtime.collectives import halo_exchange
 from repro.runtime.sharding import fcn_activation_specs, mesh_axis_sizes
@@ -251,15 +251,12 @@ class EngineFactory:
     model's ``normalize_weights``) — so f32-vs-bfp accuracy parity
     compares one weight set under two numerics, never two inits.
 
-    With a telemetry ``book`` (runtime/telemetry.CostBook) every
-    compiled engine is wrapped once, at compile time, to record its
-    per-call wall keyed by (bucket_hw, batch, plan_kind) under
-    ``stage="dispatch"`` — the non-blocking engine-call side of the
-    measured-cost loop (engines return un-materialized arrays; the
-    serving layer records the dispatch-through-materialization
-    ``stage="step"`` wall the planner's MeasuredCost overlay reads).
-    The wrapper lives inside the LRU, so cache hits return the identical
-    callable.
+    Engines are the bare jitted callables (the serving layer times each
+    call in its ``std.dispatch.call`` span, launch/serve.py).  Inside
+    them the CC tail runs under the named scope ``cc_tail`` and the
+    on-device box extraction under ``boxes``, beside the interpreter's
+    per-word scopes (core/interpreter.py), so a device trace's ops map
+    back to the layer that issued them.
     """
 
     def __init__(
@@ -269,7 +266,6 @@ class EngineFactory:
         score_thr: float = 0.5,
         link_thr: float = 0.5,
         capacity: int = 16,
-        book: Any = None,
         cc_pallas: Any = None,
         engine_bytes_budget: int = 0,
         device: Any = None,
@@ -290,7 +286,6 @@ class EngineFactory:
         self._make_model_arity = min(n_params, 3)
         self.score_thr = score_thr
         self.link_thr = link_thr
-        self.book = book
         # parameters committed to ``device`` pull every engine call that
         # uses them onto it (one replica per chip); None = default device
         self.device = device
@@ -302,15 +297,16 @@ class EngineFactory:
         # model/param caches are LRU-bounded like the engines: oversize
         # inputs clamp to an open-ended set of padded shapes (bucket_hw),
         # so unbounded dicts would leak a parameter tree per shape
-        self._models = LRUCache(capacity)
-        self._params = LRUCache(capacity)
+        self._models = batching.LRUCache(capacity)
+        self._params = batching.LRUCache(capacity)
         # the engine LRU can evict by planned activation bytes instead of
         # (only) entry count: plan_fn puts each engine with
         # weight = memplan peak bytes x batch, so a byte budget keeps the
         # RESIDENT FOOTPRINT bounded rather than the engine count —
         # engine_bytes_budget=0 keeps the pure count rule
-        self._engines = LRUCache(capacity, byte_budget=engine_bytes_budget)
-        self._memplans = LRUCache(capacity)
+        self._engines = batching.LRUCache(capacity,
+                                          byte_budget=engine_bytes_budget)
+        self._memplans = batching.LRUCache(capacity)
         self._lock = threading.Lock()
         self.stats: Dict[str, Any] = {"compiled": [], "engine_memory": []}
         self._mem_measured: Dict[Any, Dict[str, Any]] = {}
@@ -455,9 +451,6 @@ class EngineFactory:
         if fn is not None:
             return fn
         fn = self._compile(tuple(hw), int(batch), plan, precision, model)
-        if self.book is not None:
-            fn = self._timed(fn, tuple(hw), int(batch), plan_kind(plan),
-                             precision, model)
         self.stats["compiled"].append(
             {"hw": tuple(hw), "batch": int(batch),
              "plan": describe_plan(plan), "precision": precision,
@@ -466,27 +459,6 @@ class EngineFactory:
         self._engines.put(key, fn, weight=self.engine_weight_bytes(
             hw, batch, precision, model))
         return fn
-
-    def _timed(self, fn: Callable, hw, batch: int, kind: str,
-               precision: str = "f32",
-               model: str = DEFAULT_MODEL) -> Callable:
-        """Record each engine call's wall into the telemetry book.
-        This measures the DISPATCH side only — engines return pending
-        arrays, so blocking here would serialize the async pipeline."""
-        def timed(params, x, valid_q):
-            t0 = time.perf_counter()
-            out = fn(params, x, valid_q)
-            self.book.record_step(hw, batch, kind,
-                                  time.perf_counter() - t0,
-                                  stage="dispatch", precision=precision,
-                                  model=model)
-            return out
-
-        # AOT lowering stays available through the wrapper: a caller that
-        # compiles ``lower(...).compile()`` warms the same executable the
-        # timed calls then run
-        timed.lower = fn.lower
-        return timed
 
     def _tail(self, model_obj, out, valid_q):
         """The model's serving tail: named maps -> (*payload, converged).
@@ -505,26 +477,28 @@ class EngineFactory:
 
     def _label_tail(self, score, links, valid_q):
         """Batched CC labeling tail -> (labels, converged) with the
-        per-image (N,) convergence flag (iters stay internal)."""
+        per-image (N,) convergence flag (iters stay internal), under the
+        named scope ``cc_tail``."""
         from repro.models.fcn import postprocess as pp
 
         h, w = score.shape[1:]
-        mask = (
-            (jnp.arange(h)[None, :, None] < valid_q[:, 0, None, None])
-            & (jnp.arange(w)[None, None, :] < valid_q[:, 1, None, None])
-        )
-        if self.cc_pallas:
-            from repro.kernels.cc_label import cc_label_pallas
+        with jax.named_scope("cc_tail"):
+            mask = (
+                (jnp.arange(h)[None, :, None] < valid_q[:, 0, None, None])
+                & (jnp.arange(w)[None, None, :] < valid_q[:, 1, None, None])
+            )
+            if self.cc_pallas:
+                from repro.kernels.cc_label import cc_label_pallas
 
-            labels, _, converged = cc_label_pallas(
-                score, links, self.score_thr, self.link_thr,
-                valid_mask=mask, return_stats=True,
-            )
-        else:
-            labels, _, converged = pp.cc_label_batched(
-                score, links, self.score_thr, self.link_thr,
-                valid_mask=mask, return_stats=True,
-            )
+                labels, _, converged = cc_label_pallas(
+                    score, links, self.score_thr, self.link_thr,
+                    valid_mask=mask, return_stats=True,
+                )
+            else:
+                labels, _, converged = pp.cc_label_batched(
+                    score, links, self.score_thr, self.link_thr,
+                    valid_mask=mask, return_stats=True,
+                )
         return labels, converged
 
     def boxes_fn(self, hw: Tuple[int, int], batch: int,
@@ -541,9 +515,13 @@ class EngineFactory:
         fn = self._engines.get(key)
         if fn is not None:
             return fn
-        fn = jax.jit(functools.partial(
-            pp.boxes_from_labels_batched_jax, capacity=int(capacity)
-        ))
+
+        def boxes(labels):
+            with jax.named_scope("boxes"):
+                return pp.boxes_from_labels_batched_jax(
+                    labels, capacity=int(capacity))
+
+        fn = jax.jit(boxes)
         self.stats.setdefault("boxes_compiled", []).append(
             {"hw": tuple(hw), "batch": int(batch),
              "capacity": int(capacity)}
@@ -731,7 +709,7 @@ class EngineFactory:
 
     # -- introspection ---------------------------------------------------------
     @property
-    def engines(self) -> LRUCache:
+    def engines(self) -> batching.LRUCache:
         return self._engines
 
     def __len__(self) -> int:

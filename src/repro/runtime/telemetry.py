@@ -9,17 +9,23 @@ half of that loop:
   * :class:`CostBook` — the lock-guarded measurement store every layer
     writes into.  Engine step times are keyed by
     ``(bucket_hw, batch, plan_kind)`` (plus a ``stage`` dimension:
-    ``"dispatch"`` = the engine-call wall recorded by
-    runtime/executor.EngineFactory, ``"step"`` = dispatch through
-    materialization recorded by launch/serve.STDService — and a
-    ``precision`` dimension, ``"f32"``/``"bfp"``, so per-precision
-    walls never mix and a measured-cost planner can route each bucket
-    to its faster numerics); scheduler
-    stage timings / queue gauges / shed counters from
-    launch/batching.MicroBatcher land as named series in the same book.
-    Every series keeps a count, an EWMA, and a bounded window of recent
-    samples for p50/p99 — all mutations hold one lock, the same
-    stats-locking contract the PR 4 hammer tests pin on MicroBatcher.
+    ``"dispatch"`` = the engine-call wall, ``"step"`` = dispatch
+    through materialization, both recorded by launch/serve.STDService —
+    and a ``precision`` dimension, ``"f32"``/``"bfp"``, so
+    per-precision walls never mix and a measured-cost planner can route
+    each bucket to its faster numerics); scheduler stage timings /
+    queue gauges / shed counters from launch/batching.MicroBatcher land
+    as named series in the same book.  Every series keeps a count, a
+    running sum, an EWMA, and a bounded window of recent samples for
+    p50/p99 — all mutations hold one lock, the same stats-locking
+    contract the hammer tests pin on MicroBatcher.
+  * :class:`span` — the one timing helper of the served path: a context
+    manager that opens a ``jax.profiler.TraceAnnotation`` (so the span
+    lands on the device trace's clock whenever a profiler is active)
+    and records its wall into a book series.  Spans nest per thread and
+    a child inherits its parent's ``req``/``batch`` keys, so one
+    request's chain can be followed through the trace;
+    :func:`watch_gc` turns interpreter GC pauses into ``std.gc`` spans.
   * :func:`snapshot` / :func:`prometheus_text` — flat scrapeable
     ``{metric_name: value}`` export (labels are embedded in the metric
     name, Prometheus-style), surfaced by
@@ -38,18 +44,142 @@ The planner side of the loop lives in runtime/planner.py:
 enough observations.  This module never imports the planner at the top
 level's hot path beyond CostParams, and the planner does not import
 this module at all (the book is duck-typed), so the layering stays
-one-directional.
+one-directional.  JAX is imported lazily, on a span's first use.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import math
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 StepKey = Tuple[Tuple[int, int], int, str]
+
+#: the keys a child span takes from the span open around it
+INHERITED = ("req", "batch")
+_open = threading.local()            # .span: innermost open span here
+_annotation = None                   # jax.profiler.TraceAnnotation
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """``with span(name, book=..., series=..., **args):`` — one timed
+    stage of the served path.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **args)`` while a
+    profiler is active (the TraceMe check is all a span costs the trace
+    otherwise), so the span shares the device events' clock; the args
+    take the ``req``/``batch`` keys of the span open around it on this
+    thread where the call gives none.  On exit ``seconds`` holds the
+    wall, which goes into ``book`` when ``series`` names one: a named
+    series (str, :meth:`CostBook.observe`) or the keyword arguments of
+    a step series (dict, :meth:`CostBook.record_step`).  ``t0`` is the
+    ``time.perf_counter()`` reading at entry.  Keep args to a few
+    integers: they are formatted only while tracing."""
+
+    __slots__ = ("name", "args", "book", "series", "seconds", "t0",
+                 "_ann", "_up")
+
+    def __init__(self, name: str, *, book: Optional["CostBook"] = None,
+                 series: Any = None, **args):
+        self.name = name
+        self.args = args
+        self.book = book
+        self.series = series
+        self.seconds: Optional[float] = None
+
+    def __enter__(self) -> "span":
+        ann = _trace_annotation()
+        up = self._up = getattr(_open, "span", None)
+        if ann.is_enabled():
+            args = self.args
+            if up is not None:
+                args = {**{k: up.args[k] for k in INHERITED
+                           if k in up.args}, **args}
+                self.args = args
+            self._ann = ann(self.name, **args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        _open.span = self
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = dt = time.perf_counter() - self.t0
+        _open.span = self._up
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.book is not None and self.series is not None:
+            if isinstance(self.series, str):
+                self.book.observe(self.series, dt)
+            else:
+                self.book.record_step(seconds=dt, **self.series)
+
+    def note(self, **args) -> None:
+        """Add args learnt inside the span (they reach the trace while
+        it records)."""
+        self.args = {**self.args, **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+
+def current_span() -> Optional[span]:
+    """The innermost span open on the calling thread, if any."""
+    return getattr(_open, "span", None)
+
+
+# -- interpreter GC pauses -----------------------------------------------------
+# One ``gc.callbacks`` hook for the process while any sink watches: each
+# collection is a ``std.gc`` span, and its wall is appended to every
+# sink.  The hook runs inside allocations anywhere, with any lock held,
+# so it takes none: sinks are deques (atomic appends), which their
+# owners drain into a book from an ordinary call.
+_gc_sinks: Tuple[deque, ...] = ()
+_gc_lock = threading.Lock()
+_gc_open: Dict[int, span] = {}
+
+
+def _gc_hook(phase: str, info: Dict[str, int]) -> None:
+    tid = threading.get_ident()
+    if phase == "start":
+        _gc_open[tid] = span("std.gc", gen=info["generation"]).__enter__()
+        return
+    s = _gc_open.pop(tid, None)
+    if s is not None:
+        s.__exit__(None, None, None)
+        for sink in _gc_sinks:
+            sink.append(s.seconds)
+
+
+def watch_gc(sink: deque) -> None:
+    """Append each interpreter GC pause's seconds to ``sink`` (and
+    trace it as ``std.gc``) until :func:`unwatch_gc`."""
+    global _gc_sinks
+    with _gc_lock:
+        if not _gc_sinks:
+            gc.callbacks.append(_gc_hook)
+        _gc_sinks = _gc_sinks + (sink,)
+
+
+def unwatch_gc(sink: deque) -> None:
+    global _gc_sinks
+    with _gc_lock:
+        _gc_sinks = tuple(s for s in _gc_sinks if s is not sink)
+        if not _gc_sinks and _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)
 
 
 class _Series:
@@ -257,6 +387,7 @@ class CostBook:
                     out[f"{prefix}step_p99_s{lbl}"] = p99
             for name, s in sorted(self._series.items()):
                 out[f"{prefix}{name}_count"] = float(s.count)
+                out[f"{prefix}{name}_sum"] = s.total
                 if s.ewma is not None:
                     out[f"{prefix}{name}_ewma"] = s.ewma
                 p50, p99 = s.percentile(50), s.percentile(99)
