@@ -91,11 +91,14 @@ def bench_fig9_tps():
 def bench_tableIV_vmem():
     """Paper Table IV analogue: per-kernel VMEM budget from BlockSpecs
     (the resource-utilization table; v5e-class core ~ 128 MiB VMEM)."""
+    from repro.kernels.bfp_matmul.kernel import _tiles
+
     VMEM = 128 * 2**20
+    # the BFP kernel's tiles adapt to the shape: ResNet-50's widest 1x1
+    # at 512x512, batch 8 (M=131072, K=64, N=256, f16 activation)
+    _, bm, _, _, bfp_bytes = _tiles(131072, 64, 256, 2)
     rows = [
-        ("bfp_matmul_bm256_bn256_bk512",
-         2 * (256 * 512 + 512 * 256 + 256 * 16 + 256 * 16)
-         + 256 * 256 * 4),
+        (f"bfp_matmul_bm{bm}_k64_n256", bfp_bytes),
         ("winograd_bp128_bn128_bk128",
          2 * (128 * 36 * 128 * 4 + 36 * 128 * 128 * 4)
          + 36 * 128 * 128 * 4 + 128 * 16 * 128 * 4),

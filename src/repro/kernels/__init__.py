@@ -5,8 +5,9 @@ Each package ships three layers:
   ops.py     jit'd public wrapper (layout, quantization, padding)
   ref.py     pure-jnp oracle used by tests and as the interpreter fallback
 
-  bfp_matmul/       paper C2 — shared-exponent block-FP matmul, int8
-                    mantissa HBM traffic, f32 wide accumulation (§IV.C)
+  bfp_matmul/       paper C2 — a 1x1 BFP conv in one launch: the f16
+                    activation quantized in VMEM, load-time BFP weights,
+                    f32 wide accumulation (§IV.C), bias/ReLU flush
   winograd_conv/    paper C3 — F(4x4,3x3), 36 MXU contractions per tile,
                     output transform fused in-kernel
   flash_attention/  blockwise online-softmax GQA attention (prefill path)

@@ -1,29 +1,41 @@
 """BFP matmul Pallas kernel — paper C2 adapted to TPU (DESIGN.md §2).
 
-The FPGA runs fixed-point MACs on shared-exponent mantissas because DSPs
-are cheap and FP is expensive.  On TPU the MXU is already fixed-function;
-what BFP buys is *HBM/ICI bandwidth*: the kernel streams int8 mantissas
-(one int8 exponent per `block_size` values) from HBM — a 4x reduction
-versus f32 and 2x versus bf16 — dequantizes in VMEM on the VPU, and runs
-the MXU in f32 with full-width accumulation (the §IV.C wide-accumulator
-discipline: inputs are quantized, the accumulator never is).
+One launch runs a whole 1x1 BFP conv: the activation quantization
+(Algorithm 1, the paper's Fig. 6 normalization module), the MXU
+contraction with a wide f32 accumulator (§IV.C), and the conv's bias
+and ReLU epilogue (Fig. 5's per-layer datapath flags).
 
-Tiling: grid (M/bm, N/bn, K/bk), K innermost so the f32 accumulator tile
-lives in a VMEM scratch across the K sweep.  `bk` must be a multiple of
-the BFP block size so exponent tiles align with mantissa tiles.
+Operands, as the data pool holds them:
 
-Exponent layout: one slab per K tile, ``(K/bk, rows, bk/bs)``.  A block
-``(1, bm, bk/bs)`` then spans the slab's full last dimension, which the
-TPU compiler requires of any block narrower than 128 lanes (the flat
-``(rows, K/bs)`` layout is refused as soon as K spans more than one tile).
+- ``a`` (M, K): the activation, f16 storage (or f32), read straight from
+  HBM.  Each (bm, K) tile is quantized in VMEM along K in blocks of
+  ``block_size``: the block's shared exponent is the largest frexp
+  exponent of its nonzero values, each mantissa is truncated to
+  ``mantissa_bits`` fractional bits and arithmetically shifted down to
+  the shared exponent — bit for bit what ``core.bfp.quantize`` does.
+- ``w`` (K, N) f32: weights already BFP-valued along K (the load-time
+  normalization of ``FCNEngine.normalize_weights``), used as they are.
 
-VMEM budget per step (defaults bm=bn=256, bk=512, bs=32):
-    A mantissa  256*512   int8   = 128 KiB     (x2 for pipeline ping-pong)
-    B mantissa  512*256   int8   = 128 KiB
-    exponents   256*16*2  int8   =   8 KiB
-    accumulator 256*256   f32    = 256 KiB
-  ~0.9 MiB with double buffering — far under the ~16 MiB/core class
-  budget, leaving room for the compiler to widen tiles.
+Blocks never straddle a 128-lane vreg: K is padded with zeros to a
+multiple of 128 (lanes the tiled layout keeps anyway; zeros never win a
+block max), and the tile is quantized in slabs of 128 lanes.  The block
+max takes five lane rolls (1, 2, 4, 8, 16 for blocks of 32): each lane
+ends with the maximum of the 32 lanes up to it, so each block's last
+lane holds the block's; one MXU pass with a 0/1 matrix spreads it over
+the block.  Measured on a TPU v5e, this beat a butterfly of ten rolls,
+and slabs of 256 rows beat slabs of 16-128 (more independent vregs
+between the rolls' latencies).
+
+The 16-bit storage type is not a Mosaic vector type on this chip
+generation, so f16 reaches the kernel as its int16 bit pattern and is
+widened to f32 bits in VMEM (subnormals included, exactly).
+
+Grid: (M/bm, N/bn).  Every tile holds whole rows of A (bk = K, no K
+sweep); bn is N on every served shape, so the weight matrix's block
+index never changes and it is fetched once per call.  At a row tile's
+first column tile the quantization walks it into an f32 VMEM scratch;
+then one MXU contraction at ``Precision.HIGHEST`` (exact on the 11-bit
+signed mantissas) feeds the flush: + bias, ReLU, one f32 store.
 """
 from __future__ import annotations
 
@@ -34,88 +46,219 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+import repro.kernels
 
-def _bfp_matmul_kernel(
-    ma_ref,      # (bm, bk)   int8/int16 mantissas of A
-    ea_ref,      # (1, bm, bk//bs) int32 block exponents of A
-    mb_ref,      # (bk, bn)   mantissas of B
-    eb_ref,      # (1, bn, bk//bs) int32 block exponents of B (N-major)
-    o_ref,       # (bm, bn)   f32 out
-    acc_ref,     # (bm, bn)   f32 VMEM scratch
-    *,
-    block_size: int,
-    mantissa_bits: int,
-):
-    k = pl.program_id(2)
+#: rows of A per grid step at most, and the VMEM the step's blocks may take
+_ROW_CAP = 2048
+_BLOCK_BUDGET = 24 << 20
+#: rows of one quantization slab (x 128 lanes)
+_CHUNK_ROWS = 256
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # dequantize tiles in VMEM (VPU work): value = m * 2^(e - mantissa_bits)
-    # (exact power-of-two via exponent-field bitcast — see core.bfp.exp2i)
-    from repro.core.bfp import exp2i
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
 
-    ea = jnp.repeat(ea_ref[0], block_size, axis=1)              # (bm, bk)
-    a = ma_ref[...].astype(jnp.float32) * exp2i(ea - mantissa_bits)
-    eb = jnp.repeat(eb_ref[0], block_size, axis=1)              # (bn, bk)
-    b = mb_ref[...].astype(jnp.float32) * exp2i(eb - mantissa_bits).T
-    # MXU contraction with f32 (wide) accumulation; HIGHEST keeps the
-    # 11-bit signed mantissas exact (a bf16 pass would round them)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.HIGHEST)
 
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
+def _f32_bits(h: jax.Array) -> jax.Array:
+    """int16 bits of f16 values -> int32 bits of the same values as f32
+    (exact: f16 normals rebias their exponent, f16 subnormals become f32
+    normals through an exact int->float conversion).  int32 input is
+    taken as f32 bits already."""
+    if h.dtype != jnp.int16:
+        return h
+    h = h.astype(jnp.int32)                   # sign-extended
+    mag = h & 0x7FFF
+    normal = (mag << 13) + (112 << 23)        # f16 bias 15 -> f32 bias 127
+    tiny = pltpu.bitcast(mag.astype(jnp.float32) * (2.0 ** -24), jnp.int32)
+    bits = jnp.where(mag < 0x400, tiny, normal)
+    return bits | (h & jnp.int32(-(2 ** 31)))
+
+
+def _spread(block_size: int) -> jax.Array:
+    """(128, 128) 0/1: row ``i`` copies lane ``i`` to every lane of its
+    block when ``i`` is the block's last lane, else nothing."""
+    src = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+    return ((src // block_size == dst // block_size)
+            & (src % block_size == block_size - 1)).astype(jnp.float32)
+
+
+def _block_max(e: jax.Array, spread: jax.Array, block_size: int
+               ) -> jax.Array:
+    """Each lane's maximum of ``e`` (int32, 0..255) over its block of
+    ``block_size`` lanes (a power of two dividing 128).  Lane rolls by
+    1, 2, 4, ... leave each lane the maximum of the ``block_size`` lanes
+    up to it, so a block's last lane holds the block's maximum; one MXU
+    pass spreads it over the block (exact: small integers, one nonzero
+    term per sum)."""
+    s = 1
+    while s < block_size:
+        e = jnp.maximum(e, pltpu.roll(e, s, 1))        # from lane i - s
+        s *= 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    ends = jnp.where(lane % block_size == block_size - 1, e, 0)
+    return jnp.dot(ends.astype(jnp.float32), spread,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _quantize_slab(bits: jax.Array, spread: jax.Array, *, block_size: int,
+                   mantissa_bits: int, rounding: str) -> jax.Array:
+    """Algorithm 1 on f32 bit patterns (rows, 128), blocks along the
+    lanes: the dequantized values ``mantissa * 2**(exponent -
+    mantissa_bits)``.
+
+    Integer form of ``core.bfp.quantize``: the block exponent is the
+    largest exponent field among nonzero values (zeros carry field 0 and
+    never win; an all-zero block quantizes to zeros); ``mi`` is the
+    24-bit significand cut to ``mantissa_bits`` fractional bits of the
+    frexp mantissa, signed; the shift by the exponent distance (capped
+    at 31) floors negative values.  f32 subnormals are outside the
+    contract (f16 storage never produces one)."""
+    e = (bits >> 23) & 0xFF
+    top = _block_max(e, spread, block_size)
+    d = jnp.minimum(top - e, 31)
+    # implicit bit only for nonzero values
+    sig = (bits & 0x7FFFFF) | (jnp.minimum(e, 1) << 23)
+    mi = sig >> (24 - mantissa_bits)
+    neg = bits >> 31                                   # 0 or -1
+    mi = (mi ^ neg) - neg
+    if rounding == "nearest":
+        half = jnp.where(d > 0, 1 << jnp.maximum(d - 1, 0), 0)
+        mi = mi + jnp.where(mi > 0, half, jnp.where(mi < 0, -half, 0))
+    q = mi >> d
+    # 2**(xi - mantissa_bits), xi = field - 126, clamped as exp2i
+    scale = jnp.clip(top + 1 - mantissa_bits, 1, 254) << 23
+    return q.astype(jnp.float32) * pltpu.bitcast(scale, jnp.float32)
+
+
+def _kernel(a_ref, w_ref, b_ref, o_ref, q_ref, *, block_size: int,
+            mantissa_bits: int, rounding: str, relu: bool, rows: int):
+    """a: (bm, Kp) int16/int32 bits, Kp a multiple of 128; w: (K, bn)
+    f32, K <= Kp; b: (1, bn) f32; o: (bm, bn) f32; q: (bm, Kp) f32
+    scratch of the quantized tile, filled at the row tile's first
+    column tile."""
+    kp, k = a_ref.shape[1], w_ref.shape[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _quantize():
+        spread = _spread(block_size)
+
+        def chunk(c, carry):
+            r = pl.multiple_of(c * rows, rows)
+            # 128-lane slabs: blocks never straddle one
+            for j in range(0, kp, 128):
+                q_ref[pl.ds(r, rows), pl.ds(j, 128)] = _quantize_slab(
+                    _f32_bits(a_ref[pl.ds(r, rows), pl.ds(j, 128)]),
+                    spread, block_size=block_size,
+                    mantissa_bits=mantissa_bits, rounding=rounding)
+            return carry
+
+        jax.lax.fori_loop(0, a_ref.shape[0] // rows, chunk, 0)
+
+    q = q_ref[...] if k == kp else q_ref[:, :k]
+    acc = jnp.dot(q, w_ref[...], preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)
+    acc = acc + b_ref[...]
+    if relu:
+        acc = jnp.maximum(acc, 0.0)
+    o_ref[...] = acc
+
+
+def _tiles(m: int, k: int, n: int, itemsize: int):
+    """(rows of A as called, bm, bn, chunk rows, vmem bytes).  ``bn`` is
+    N unless the weight matrix overflows half the budget (then the
+    largest 128-lane multiple dividing N that fits); ``bm`` the largest
+    power-of-two row tile that divides ``m`` and fits the rest.  An
+    ``m`` with no tile of a whole sublane group is one block up to 256
+    rows, else padded to a multiple of 256."""
+    kp, k8 = _lanes(k), -(-k // 8) * 8 + 8
+    w_bytes = lambda bn: 2 * k8 * _lanes(bn) * 4    # double-buffered
+    bn = n
+    if w_bytes(n) > _BLOCK_BUDGET // 2 and n % 128 == 0:
+        bn = 128
+        while (n % (2 * bn) == 0
+               and w_bytes(2 * bn) <= _BLOCK_BUDGET // 2):
+            bn *= 2
+    # A (double-buffered), the quantized scratch, out (double-buffered)
+    # and the dot's result, per row
+    per_row = kp * (2 * itemsize + 4) + 3 * _lanes(bn) * 4
+    sub = 32 // itemsize                      # 16 rows for 16-bit input
+    bm = 1
+    while (m % (2 * bm) == 0 and 2 * bm <= _ROW_CAP
+           and (2 * bm <= sub
+                or 2 * bm * per_row + w_bytes(bn) <= _BLOCK_BUDGET)):
+        bm *= 2
+    if bm < sub:
+        if m > 256:
+            return _tiles(-(-m // 256) * 256, k, n, itemsize)
+        bm = m
+    rows = bm if bm % sub else min(bm, _CHUNK_ROWS)
+    return m, bm, bn, rows, bm * per_row + w_bytes(bn)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "block_size", "mantissa_bits", "bm", "bn", "bk", "interpret",
-    ),
+    static_argnames=("block_size", "mantissa_bits", "rounding", "relu",
+                     "interpret"),
 )
 def bfp_matmul_quantized(
-    ma: jax.Array,   # (M, K) int mantissas
-    ea: jax.Array,   # (M, K//bs) int32 exponents
-    mb: jax.Array,   # (K, N) int mantissas
-    eb: jax.Array,   # (N, K//bs) int32 exponents
+    a: jax.Array,                 # (M, K) f16 or f32 activation
+    w: jax.Array,                 # (K, N) BFP-valued f32 weights
+    b: jax.Array | None = None,   # (N,) bias, applied in the flush
     *,
     block_size: int,
     mantissa_bits: int,
-    bm: int = 256,
-    bn: int = 256,
-    bk: int = 512,
-    interpret: bool = False,
+    rounding: str = "trunc",
+    relu: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    M, K = ma.shape
-    K2, N = mb.shape
-    assert K == K2 and K % block_size == 0
-    bm = min(bm, M)
-    bn = min(bn, N)
-    bk = min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    assert bk % block_size == 0
-    ebk = bk // block_size
-    # (rows, K/bs) -> (K/bk, rows, bk/bs): one exponent slab per K tile
-    slabs = lambda e: e.reshape(e.shape[0], K // bk, ebk).transpose(1, 0, 2)
-
-    return pl.pallas_call(
-        functools.partial(
-            _bfp_matmul_kernel,
-            block_size=block_size,
-            mantissa_bits=mantissa_bits,
-        ),
-        grid=(M // bm, N // bn, K // bk),
+    """(M, N) f32 = [relu](quantize(a) @ w + b), ``a`` quantized along K
+    in the kernel, ``block_size`` a power of two up to 128.  Tiles adapt
+    to M, K and N.  A K of no 128-lane multiple is padded with zeros
+    (they fill lanes the tiled layout keeps anyway), an ``M`` with no
+    power-of-two tile of a sublane group with zero rows."""
+    if interpret is None:
+        interpret = repro.kernels.default_interpret()
+    if rounding not in ("trunc", "nearest"):
+        raise ValueError(rounding)
+    m, k = a.shape
+    k2, n = w.shape
+    if k != k2 or block_size & (block_size - 1) or block_size > 128 \
+            or not 0 < mantissa_bits < 24:
+        raise ValueError(f"K={k} (weights {k2}), block_size {block_size}, "
+                         f"mantissa_bits {mantissa_bits}: need equal K, a "
+                         f"power-of-two block up to 128 and 1-23 bits")
+    w = w.astype(jnp.float32)
+    if a.dtype == jnp.float16:
+        bits = jax.lax.bitcast_convert_type(a, jnp.int16)
+    else:
+        bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.int32)
+    if k % 128:
+        # the lanes a narrow K leaves empty in the tiled layout, as
+        # zeros: they never win a block max and meet no weight row
+        bits = jnp.pad(bits, ((0, 0), (0, (-k) % 128)))
+    mp, bm, bn, rows, vmem = _tiles(m, k, n, bits.dtype.itemsize)
+    if mp != m:
+        bits = jnp.pad(bits, ((0, mp - m), (0, 0)))
+    bias = (jnp.zeros((1, n), jnp.float32) if b is None
+            else b.astype(jnp.float32).reshape(1, n))
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_size=block_size,
+                          mantissa_bits=mantissa_bits, rounding=rounding,
+                          relu=relu, rows=rows),
+        grid=(mp // bm, n // bn),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, bm, ebk), lambda i, j, k: (k, i, 0)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn, ebk), lambda i, j, k: (k, j, 0)),
+            pl.BlockSpec((bm, _lanes(k)), lambda i, j: (i, 0)),
+            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, _lanes(k)), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(min(max(vmem + (16 << 20), 32 << 20),
+                                     100 << 20))),
         interpret=interpret,
-    )(ma, slabs(ea), mb, slabs(eb))
+    )(bits, w, bias)
+    return out if mp == m else out[:m]

@@ -214,6 +214,9 @@ class STDService:
         self.memplan_enabled = bool(base.memplan)
         self.activation_budget_bytes = activation_budget_bytes
         self._bucket_caps: Dict[Tuple[int, int], int] = {}
+        # engines this service has dispatched to (their kernel words are
+        # in the book once, at the first dispatch)
+        self._built: set = set()
         self.max_wait_ms = max_wait_ms
         self.batch_round = batch_round
         self.tall_plan = tall_plan
@@ -413,6 +416,12 @@ class STDService:
         fn = self.factory.plan_fn(hw, b, plan, self.precision,
                                   self.model_name)
         params = self.factory.params(hw, self.precision, self.model_name)
+        if (hw, b, plan) not in self._built:
+            self._built.add((hw, b, plan))
+            engine = self.factory.model(hw, self.precision,
+                                        self.model_name).engine
+            for name, n in engine.kernel_words(params).items():
+                self.book.set_gauge(name, n)
         with span("std.dispatch.call", book=self.book, series=dict(
                 hw=hw, batch=b, kind=kind, stage="dispatch",
                 precision=self.precision, model=self.model_name)) as call:

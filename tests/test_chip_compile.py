@@ -61,10 +61,14 @@ def _compile_text(one_chip, fn, *shapes):
 WINOGRAD = [(1, 128, 128, 64, 64), (8, 64, 64, 128, 128),
             (8, 32, 32, 256, 256), (8, 16, 16, 512, 512),
             (1, 512, 512, 64, 64)]
-# 1x1 convs as matmuls, (M = batch*H*W, K = Cin, N = Cout); K=1024 spans
-# two K tiles, K=288 is a U-merge concat that is not a tile multiple
+# 1x1 convs as matmuls through the public bfp_matmul, (M = batch*H*W,
+# K = Cin, N = Cout); K=288 is a U-merge concat of no 128-lane multiple
 BFP = [(4096, 1024, 256), (131072, 64, 256), (8192, 2048, 512),
        (131072, 288, 32), (131072, 32, 9)]
+# the fused 1x1 kernel as the engine calls it: f16 activation (M, K),
+# load-time f32 weights (K, N), bias and ReLU in the flush
+FUSED_1X1 = [(131072, 64, 256), (32768, 1152, 128), (8192, 2048, 9),
+             (131072, 288, 32), (2048, 512, 2048)]
 # (batch, h, w) label planes: 128 wide (512 bucket), 64 (256 bucket),
 # and a 160-wide plane that pads to two 128-lane tiles
 CC = [(8, 128, 128), (8, 64, 64), (2, 128, 160)]
@@ -95,6 +99,20 @@ def test_bfp_matmul_compiles(one_chip, no_cache, shape):
                                 interpret=False),
         ((m, k), jnp.float32), ((k, n), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", FUSED_1X1, ids=str)
+def test_fused_bfp_conv1x1_compiles(one_chip, no_cache, shape):
+    from repro.kernels.bfp_matmul.kernel import bfp_matmul_quantized
+
+    m, k, n = shape
+    text = _compile_text(
+        one_chip,
+        lambda a, w, b: bfp_matmul_quantized(
+            a, w, b, block_size=32, mantissa_bits=10, relu=True,
+            interpret=False),
+        ((m, k), jnp.float16), ((k, n), jnp.float32), ((n,), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("shape", CC, ids=str)

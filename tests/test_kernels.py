@@ -41,6 +41,70 @@ class TestBFPMatmulKernel:
             jnp.max(jnp.abs(ref))) < 0.05
 
 
+def _served_activation(m, k, seed):
+    """f16 activations as the data pool holds them: exponents spread
+    over 30 binades, all-zero blocks, f16 subnormals, and negative
+    values where the arithmetic shift floors."""
+    r = np.random.default_rng(seed)
+    x = r.standard_normal((m, k)) * np.exp2(r.integers(-20, 10, (m, k)))
+    x[:, 32:64] = 0.0
+    x[::3] = -np.abs(x[::3])
+    x[1::5, :32] = 3e-6
+    return jnp.asarray(x.astype(np.float16))
+
+
+class TestFusedBFPMatmulKernel:
+    """The one-launch 1x1 kernel: f16 activation quantized in VMEM,
+    load-time BFP weights taken as they are, bias and ReLU in the
+    flush."""
+
+    # the served (K, N) classes of ResNet-50 PixelLink's 1x1 convs; M
+    # spans two row tiles where K is narrow
+    @pytest.mark.parametrize("m,k,n,relu", [
+        (4096, 32, 9, False), (4096, 64, 32, True), (256, 288, 64, False),
+        (256, 576, 256, True), (64, 1152, 2048, True),
+        (64, 2048, 9, False), (128, 2048, 256, True),
+    ])
+    def test_vs_ref(self, m, k, n, relu):
+        from repro.core import bfp
+        from repro.kernels.bfp_matmul.kernel import bfp_matmul_quantized
+        from repro.kernels.bfp_matmul.ref import bfp_matmul_ref
+
+        a = _served_activation(m, k, k + n)
+        r = np.random.default_rng(n)
+        w = bfp.roundtrip(jnp.asarray(r.standard_normal((k, n)),
+                                      jnp.float32) / np.sqrt(k), axis=0)
+        b = jnp.asarray(r.standard_normal(n), jnp.float32)
+        got = bfp_matmul_quantized(a, w, b, block_size=32,
+                                   mantissa_bits=10, relu=relu,
+                                   interpret=True)
+        want = bfp_matmul_ref(a.astype(jnp.float32), w) + b
+        if relu:
+            want = jnp.maximum(want, 0.0)
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(got, want, atol=1e-6 * scale, rtol=1e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float16, jnp.float32])
+    @pytest.mark.parametrize("rounding", ["trunc", "nearest"])
+    def test_in_kernel_quantization_is_algorithm1(self, dtype, rounding):
+        """Identity weights return the quantized activation itself: each
+        value equals core.bfp.quantize's mantissa * 2**(exponent - 10)
+        bit for bit."""
+        from repro.core import bfp
+        from repro.kernels.bfp_matmul.kernel import bfp_matmul_quantized
+
+        k = 288
+        a = _served_activation(256, k, 7).astype(dtype)
+        got = bfp_matmul_quantized(a, jnp.eye(k), block_size=32,
+                                   mantissa_bits=10, rounding=rounding,
+                                   interpret=True)
+        q = bfp.quantize(a.astype(jnp.float32), block_size=32,
+                         mantissa_bits=10, rounding=rounding)
+        exp = np.repeat(np.asarray(q.exponent), 32, axis=1)
+        want = np.ldexp(np.asarray(q.mantissa, np.float64), exp - 10)
+        np.testing.assert_array_equal(np.asarray(got, np.float64), want)
+
+
 class TestWinogradKernel:
     @settings(max_examples=10, deadline=None)
     @given(
