@@ -56,35 +56,39 @@ def winograd_conv2d(
     tw = -(-out_w // wg.TILE_OUT)
     need_h = th * wg.TILE_OUT + 2
     need_w = tw * wg.TILE_OUT + 2
-    xp = jnp.pad(
-        x.astype(jnp.float32),
-        ((0, 0), (ph, need_h - h - ph), (ph, need_w - wd - ph), (0, 0)),
-    )
-    # tile extraction + input transform (layout work — XLA)
-    idx_h = (jnp.arange(th) * wg.TILE_OUT)[:, None] + jnp.arange(wg.TILE_IN)
-    idx_w = (jnp.arange(tw) * wg.TILE_OUT)[:, None] + jnp.arange(wg.TILE_IN)
-    tiles = xp[:, idx_h][:, :, :, idx_w]          # (N, th, 6, tw, 6, C)
-    tiles = jnp.moveaxis(tiles, 2, 3)             # (N, th, tw, 6, 6, C)
-    v = wg.transform_input(jnp.moveaxis(tiles, -1, -3))  # (N,th,tw,C,6,6)
     P = n * th * tw
-    v = v.reshape(P, cin, 36).transpose(2, 0, 1)  # (36, P, Cin)
-    u = wg.transform_weights(w.astype(jnp.float32))      # (6,6,Cin,Cout)
-    u = u.reshape(36, cin, cout)
-
-    # pad P/Cin/Cout to tile multiples for the kernel grid
     bp_ = min(bp, P)
     bn_ = min(bn, cout)
     bk_ = min(bk, cin)
-    vp = _pad_axis(_pad_axis(v, bp_, 1), bk_, 2)
-    up = _pad_axis(_pad_axis(u, bk_, 1), bn_, 2)
-    bias = None if b is None else _pad_axis(b.astype(jnp.float32), bn_, 0)
+    # everything around the kernel call is layout work in XLA: scope
+    # winograd_io (what the benchmark's winograd_io_share reads)
+    with jax.named_scope("winograd_io"):
+        xp = jnp.pad(
+            x.astype(jnp.float32),
+            ((0, 0), (ph, need_h - h - ph), (ph, need_w - wd - ph), (0, 0)),
+        )
+        # tile extraction + input transform
+        idx_h = (jnp.arange(th) * wg.TILE_OUT)[:, None] + jnp.arange(
+            wg.TILE_IN)
+        idx_w = (jnp.arange(tw) * wg.TILE_OUT)[:, None] + jnp.arange(
+            wg.TILE_IN)
+        tiles = xp[:, idx_h][:, :, :, idx_w]          # (N, th, 6, tw, 6, C)
+        tiles = jnp.moveaxis(tiles, 2, 3)             # (N, th, tw, 6, 6, C)
+        v = wg.transform_input(jnp.moveaxis(tiles, -1, -3))  # (N,th,tw,C,6,6)
+        v = v.reshape(P, cin, 36).transpose(2, 0, 1)  # (36, P, Cin)
+        u = wg.transform_weights(w.astype(jnp.float32))      # (6,6,Cin,Cout)
+        u = u.reshape(36, cin, cout)
+        # pad P/Cin/Cout to tile multiples for the kernel grid
+        vp = _pad_axis(_pad_axis(v, bp_, 1), bk_, 2)
+        up = _pad_axis(_pad_axis(u, bk_, 1), bn_, 2)
+        bias = None if b is None else _pad_axis(b.astype(jnp.float32), bn_, 0)
     y = winograd_tile_matmul(
         vp, up, bias, bp=bp_, bn=bn_, bk=bk_, relu=relu,
         interpret=interpret,
-    )[:, :P, :cout]                               # (16, P, Cout)
-
-    y = y.reshape(wg.TILE_OUT, wg.TILE_OUT, n, th, tw, cout)
-    y = y.transpose(2, 3, 0, 4, 1, 5).reshape(
-        n, th * wg.TILE_OUT, tw * wg.TILE_OUT, cout
-    )[:, :out_h, :out_w, :]
-    return y
+    )
+    with jax.named_scope("winograd_io"):
+        y = y[:, :P, :cout]                           # (16, P, Cout)
+        y = y.reshape(wg.TILE_OUT, wg.TILE_OUT, n, th, tw, cout)
+        return y.transpose(2, 3, 0, 4, 1, 5).reshape(
+            n, th * wg.TILE_OUT, tw * wg.TILE_OUT, cout
+        )[:, :out_h, :out_w, :]

@@ -57,10 +57,14 @@ def _compile_text(one_chip, fn, *shapes):
 
 
 # ResNet-50 / VGG-16 3x3 stride-1 convs at 512x512, batch 1 and 8:
-# (batch, H, W, Cin, Cout)
+# (batch, H, W, Cin, Cout); the last four are VGG-16's batch-8 convs at
+# the full plane and at 256 (conv1_1 with its 3-channel input, conv1_2,
+# conv2_1, conv2_2)
 WINOGRAD = [(1, 128, 128, 64, 64), (8, 64, 64, 128, 128),
             (8, 32, 32, 256, 256), (8, 16, 16, 512, 512),
-            (1, 512, 512, 64, 64)]
+            (1, 512, 512, 64, 64),
+            (8, 512, 512, 3, 64), (8, 512, 512, 64, 64),
+            (8, 256, 256, 64, 128), (8, 256, 256, 128, 128)]
 # 1x1 convs as matmuls through the public bfp_matmul, (M = batch*H*W,
 # K = Cin, N = Cout); K=288 is a U-merge concat of no 128-lane multiple
 BFP = [(4096, 1024, 256), (131072, 64, 256), (8192, 2048, 512),

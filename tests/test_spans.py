@@ -361,3 +361,28 @@ class TestEngineScopes:
                  for m in re.finditer(r"w\d{3}\.([a-z0-9_]+)", n)}
         assert {"conv1x1", "conv3x3", "conv_strided", "pool", "upsample",
                 "sigmoid"} <= kinds
+
+
+def test_winograd_layout_work_under_winograd_io():
+    """Everything the 3x3 path does in XLA around its kernel (the pad,
+    the tile gather, the input and weight transforms, the output's
+    untiling) carries the ``winograd_io`` scope; the kernel call does
+    not."""
+    from repro.kernels.winograd_conv import ops as wops
+
+    def conv(x, w, b):
+        with jax.named_scope("w001.conv3x3"):
+            return wops.winograd_conv2d(x, w, b, relu=True, interpret=True)
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32)
+              for s in ((2, 16, 16, 8), (3, 3, 8, 16), (16,))]
+    names = [n for n in op_names(jax.jit(conv).lower(*shapes).compile()
+                                 .as_text()) if n]
+    io = [n for n in names if "/winograd_io/" in n]
+    for op in ("/pad", "/gather", "/dot_general", "/transpose"):
+        assert any(n.endswith(op) for n in io), op
+    kernel = [n for n in names if "winograd_tile_matmul" in n]
+    assert kernel and not any("winograd_io" in n for n in kernel)
+    assert all("winograd_io" in n or "winograd_tile_matmul" in n
+               or n.endswith("jit(winograd_conv2d)")
+               for n in names if "winograd_conv2d" in n)
