@@ -70,6 +70,14 @@ def word_kind(mc: Microcode, spec) -> str:
 #: parameter key of a 1x1 conv's load-time weights: the BFP-normalized
 #: (Cin, Cout) matrix (``FCNEngine.normalize_weights``)
 MATRIX = "w_kn"
+#: parameter key of any other conv's load-time weights: the BFP-normalized
+#: HWIO kernel, used as it is
+KERNEL = "w_bfp"
+#: the least input channels for which a conv's activation round trip runs
+#: as one Pallas pass.  On a TPU v5e XLA's fused round trip won at 3 and 16
+#: channels (rows that fill a few of a tile's 128 lanes), the two tied at
+#: 32, and from 64 up the pass won by a wide margin
+ROWS_MIN_CIN = 32
 
 
 def _he_init(key, shape, fan_in):
@@ -162,44 +170,73 @@ class FCNEngine:
                 )
                 p = {"w": w, "b": b}
             if self.bfp is not None and "w" in p:
-                w = bfp_lib.roundtrip(
-                    p.pop("w"),
-                    block_size=self.bfp.block_size,
-                    mantissa_bits=self.bfp.mantissa_bits,
-                    axis=-2,                       # block along Cin (K dim)
-                    rounding=self.bfp.rounding,
-                )
-                if word_kind(self.program.words[idx], spec) == "conv1x1":
+                w = self._bfp_weights(p.pop("w"))
+                if spec.op != "conv":
+                    p["w"] = w
+                elif word_kind(self.program.words[idx], spec) == "conv1x1":
                     # a 1x1 conv is a matmul: its (Cin, Cout) matrix,
                     # marked by its key, is what the BFP matmul kernel
                     # takes as it is
                     p[MATRIX] = w.reshape(w.shape[-2:])
                 else:
-                    p["w"] = w
+                    p[KERNEL] = w
             out[name] = p
         return out
 
+    def _bfp_weights(self, w):
+        """Algorithm 1 on a conv kernel, blocked along Cin (the K dim)."""
+        return bfp_lib.roundtrip(
+            w.astype(jnp.float32),
+            block_size=self.bfp.block_size,
+            mantissa_bits=self.bfp.mantissa_bits,
+            axis=-2,
+            rounding=self.bfp.rounding,
+        )
+
     def kernel_words(self, params) -> Dict[str, int]:
-        """The 1x1 words that run the BFP matmul kernel on ``params``:
-        on load-time matrices (``bfp1x1_fused_words``) or on raw weights
-        quantized in the call (``bfp1x1_fallback_words``)."""
-        fused = fallback = 0
+        """How the conv words of a BFP engine run on ``params``.  The 1x1
+        words on the BFP matmul kernel: on load-time matrices
+        (``bfp1x1_fused_words``) or on raw weights quantized in the call
+        (``bfp1x1_fallback_words``).  The other conv words: those whose
+        activation round trip runs as one Pallas pass
+        (``bfp_rows_words``), and those whose raw weights are quantized
+        in every call (``bfp_incall_weight_words``)."""
+        fused = fallback = rows = incall = 0
         for idx, name in self.program.weight_bindings.items():
-            if self._bfp_matmul(self.program.words[idx],
-                                self.program.layer_specs[idx]):
-                if MATRIX in params.get(name, {}):
-                    fused += 1
-                else:
-                    fallback += 1
+            mc, spec = self.program.words[idx], self.program.layer_specs[idx]
+            if self.bfp is None or spec.op != "conv":
+                continue
+            raw = "w" in params.get(name, {})
+            if self._bfp_matmul(mc, spec):
+                fused += not raw
+                fallback += raw
+            else:
+                rows += self._bfp_rows(mc, spec)
+                incall += raw
         return {"bfp1x1_fused_words": fused,
-                "bfp1x1_fallback_words": fallback}
+                "bfp1x1_fallback_words": fallback,
+                "bfp_rows_words": rows,
+                "bfp_incall_weight_words": incall}
+
+    def _pallas_bfp(self) -> bool:
+        return (self.bfp is not None and self.use_pallas
+                and self.mode == "optimized")
 
     def _bfp_matmul(self, mc: Microcode, spec) -> bool:
         """Whether a word runs on the BFP matmul kernel: a 1x1 stride-1
         conv of an optimized BFP engine with the Pallas kernels on."""
-        return (self.bfp is not None and self.use_pallas
-                and self.mode == "optimized"
-                and word_kind(mc, spec) == "conv1x1")
+        return self._pallas_bfp() and word_kind(mc, spec) == "conv1x1"
+
+    def _bfp_rows(self, mc: Microcode, spec) -> bool:
+        """Whether a conv word off the BFP matmul kernel round-trips its
+        activation in one Pallas pass (``bfp_roundtrip_rows``): an
+        optimized BFP engine with the Pallas kernels on, blocks of a
+        power of two up to 128 lanes, and at least ``ROWS_MIN_CIN``
+        input channels."""
+        bs = self.bfp.block_size if self.bfp is not None else 0
+        return (self._pallas_bfp() and not self._bfp_matmul(mc, spec)
+                and 0 < bs <= 128 and not bs & (bs - 1)
+                and mc.in_ch >= ROWS_MIN_CIN)
 
     # -- datapath units -------------------------------------------------------
     def _conv(self, x, p, mc: Microcode, spec, *, transposed: bool = False,
@@ -222,13 +259,8 @@ class FCNEngine:
             wm = p.get(MATRIX)
             if wm is None:
                 with jax.named_scope("bfp_roundtrip"):
-                    wm = bfp_lib.roundtrip(
-                        p["w"].astype(jnp.float32),
-                        block_size=self.bfp.block_size,
-                        mantissa_bits=self.bfp.mantissa_bits,
-                        axis=-2,
-                        rounding=self.bfp.rounding,
-                    ).reshape(p["w"].shape[-2:])
+                    wm = self._bfp_weights(p["w"]).reshape(
+                        p["w"].shape[-2:])
             n, hh, ww, cin = x.shape
             # the views in and out of the kernel: scope bfp_matmul_io
             with jax.named_scope("bfp_matmul_io"):
@@ -242,7 +274,13 @@ class FCNEngine:
             )
             with jax.named_scope("bfp_matmul_io"):
                 return y.reshape(n, hh, ww, -1)
-        w = p["w"] if "w" in p else p[MATRIX][None, None]
+        # load-time weights (normalize_weights) are used as they are;
+        # raw ones are normalized in the call, so the Fig. 4 branch holds
+        # whether or not normalize_weights() ran offline
+        raw = "w" in p
+        w = p["w"] if raw else p.get(KERNEL, p.get(MATRIX))
+        if w.ndim == 2:
+            w = w[None, None]
         if transposed:
             # transposed-image mode: transpose the weight kernels (paper:
             # "transposing the corresponding weight kernels and modifying
@@ -253,25 +291,26 @@ class FCNEngine:
             # Algorithm 1 on both operands: scope bfp_roundtrip (what the
             # benchmark's quantize_share reads)
             with jax.named_scope("bfp_roundtrip"):
-                x = bfp_lib.roundtrip(
-                    x.astype(jnp.float32),
-                    block_size=self.bfp.block_size,
-                    mantissa_bits=self.bfp.mantissa_bits,
-                    axis=-1,
-                    rounding=self.bfp.rounding,
-                )
-                # weights quantize in-call too (paper Fig. 4's
-                # normalization branch must hold whether or not the
-                # caller ran normalize_weights() offline — trunc rounding
-                # is idempotent, so pre-normalized weights pass through
-                # unchanged)
-                w = bfp_lib.roundtrip(
-                    w.astype(jnp.float32),
-                    block_size=self.bfp.block_size,
-                    mantissa_bits=self.bfp.mantissa_bits,
-                    axis=-2,                       # block along Cin (K dim)
-                    rounding=self.bfp.rounding,
-                )
+                if self._bfp_rows(mc, spec):
+                    from repro.kernels.bfp_matmul.kernel import (
+                        bfp_roundtrip_rows)
+
+                    x = bfp_roundtrip_rows(
+                        x.reshape(-1, x.shape[-1]),
+                        block_size=self.bfp.block_size,
+                        mantissa_bits=self.bfp.mantissa_bits,
+                        rounding=self.bfp.rounding,
+                    ).reshape(x.shape)
+                else:
+                    x = bfp_lib.roundtrip(
+                        x.astype(jnp.float32),
+                        block_size=self.bfp.block_size,
+                        mantissa_bits=self.bfp.mantissa_bits,
+                        axis=-1,
+                        rounding=self.bfp.rounding,
+                    )
+                if raw:
+                    w = self._bfp_weights(w)
         x = x.astype(jnp.float32)
         w = w.astype(jnp.float32)
         if depthwise:

@@ -7,7 +7,9 @@ Each package ships three layers:
 
   bfp_matmul/       paper C2 — a 1x1 BFP conv in one launch: the f16
                     activation quantized in VMEM, load-time BFP weights,
-                    f32 wide accumulation (§IV.C), bias/ReLU flush
+                    f32 wide accumulation (§IV.C), bias/ReLU flush; and
+                    the same quantization as one round-trip pass over the
+                    activation of every other conv
   winograd_conv/    paper C3 — F(4x4,3x3), 36 MXU contractions per tile,
                     output transform fused in-kernel
   flash_attention/  blockwise online-softmax GQA attention (prefill path)

@@ -36,6 +36,11 @@ index never changes and it is fetched once per call.  At a row tile's
 first column tile the quantization walks it into an f32 VMEM scratch;
 then one MXU contraction at ``Precision.HIGHEST`` (exact on the 11-bit
 signed mantissas) feeds the flush: + bias, ReLU, one f32 store.
+
+``bfp_roundtrip_rows`` runs the same quantization alone, for the convs
+the kernels above do not take (3x3, strided, depthwise): one pass over
+(M, C) rows, f16 bits in, the dequantized f32 values out, each 128-lane
+slab of a row tile quantized as in the matmul's prologue.
 """
 from __future__ import annotations
 
@@ -53,6 +58,8 @@ _ROW_CAP = 2048
 _BLOCK_BUDGET = 24 << 20
 #: rows of one quantization slab (x 128 lanes)
 _CHUNK_ROWS = 256
+#: rows per grid step at most of the round-trip pass
+_ROWS_CAP = 4096
 
 
 def _lanes(n: int) -> int:
@@ -131,6 +138,25 @@ def _quantize_slab(bits: jax.Array, spread: jax.Array, *, block_size: int,
     return q.astype(jnp.float32) * pltpu.bitcast(scale, jnp.float32)
 
 
+def _check(block_size: int, mantissa_bits: int, rounding: str) -> None:
+    if rounding not in ("trunc", "nearest"):
+        raise ValueError(rounding)
+    if block_size & (block_size - 1) or not 0 < block_size <= 128 \
+            or not 0 < mantissa_bits < 24:
+        raise ValueError(f"block_size {block_size}, mantissa_bits "
+                         f"{mantissa_bits}: need a power-of-two block up to "
+                         f"128 and 1-23 bits")
+
+
+def _as_bits(a: jax.Array) -> jax.Array:
+    """The kernels' view of an activation: f16 as its int16 bits (no
+    Mosaic vector type on this chip generation), anything else as the
+    int32 bits of its f32 value."""
+    if a.dtype == jnp.float16:
+        return jax.lax.bitcast_convert_type(a, jnp.int16)
+    return jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.int32)
+
+
 def _kernel(a_ref, w_ref, b_ref, o_ref, q_ref, *, block_size: int,
             mantissa_bits: int, rounding: str, relu: bool, rows: int):
     """a: (bm, Kp) int16/int32 bits, Kp a multiple of 128; w: (K, bn)
@@ -164,13 +190,31 @@ def _kernel(a_ref, w_ref, b_ref, o_ref, q_ref, *, block_size: int,
     o_ref[...] = acc
 
 
+def _row_block(m: int, sub: int, cap: int, fits):
+    """(rows as called, bm): ``bm`` the largest power-of-two row tile up
+    to ``cap`` that divides ``m`` and ``fits``.  An ``m`` with no tile of
+    a whole sublane group (``sub`` rows) is one block up to 256 rows,
+    else padded to a multiple of 256."""
+    bm = 1
+    while (m % (2 * bm) == 0 and 2 * bm <= cap
+           and (2 * bm <= sub or fits(2 * bm))):
+        bm *= 2
+    if bm >= sub:
+        return m, bm
+    if m > 256:
+        return _row_block(-(-m // 256) * 256, sub, cap, fits)
+    return m, m
+
+
+def _vmem_limit(vmem: int) -> int:
+    return int(min(max(vmem + (16 << 20), 32 << 20), 100 << 20))
+
+
 def _tiles(m: int, k: int, n: int, itemsize: int):
     """(rows of A as called, bm, bn, chunk rows, vmem bytes).  ``bn`` is
     N unless the weight matrix overflows half the budget (then the
-    largest 128-lane multiple dividing N that fits); ``bm`` the largest
-    power-of-two row tile that divides ``m`` and fits the rest.  An
-    ``m`` with no tile of a whole sublane group is one block up to 256
-    rows, else padded to a multiple of 256."""
+    largest 128-lane multiple dividing N that fits); ``bm`` the row tile
+    of ``_row_block`` that fits the rest."""
     kp, k8 = _lanes(k), -(-k // 8) * 8 + 8
     w_bytes = lambda bn: 2 * k8 * _lanes(bn) * 4    # double-buffered
     bn = n
@@ -183,15 +227,9 @@ def _tiles(m: int, k: int, n: int, itemsize: int):
     # and the dot's result, per row
     per_row = kp * (2 * itemsize + 4) + 3 * _lanes(bn) * 4
     sub = 32 // itemsize                      # 16 rows for 16-bit input
-    bm = 1
-    while (m % (2 * bm) == 0 and 2 * bm <= _ROW_CAP
-           and (2 * bm <= sub
-                or 2 * bm * per_row + w_bytes(bn) <= _BLOCK_BUDGET)):
-        bm *= 2
-    if bm < sub:
-        if m > 256:
-            return _tiles(-(-m // 256) * 256, k, n, itemsize)
-        bm = m
+    m, bm = _row_block(
+        m, sub, _ROW_CAP,
+        lambda b: b * per_row + w_bytes(bn) <= _BLOCK_BUDGET)
     rows = bm if bm % sub else min(bm, _CHUNK_ROWS)
     return m, bm, bn, rows, bm * per_row + w_bytes(bn)
 
@@ -219,20 +257,13 @@ def bfp_matmul_quantized(
     power-of-two tile of a sublane group with zero rows."""
     if interpret is None:
         interpret = repro.kernels.default_interpret()
-    if rounding not in ("trunc", "nearest"):
-        raise ValueError(rounding)
+    _check(block_size, mantissa_bits, rounding)
     m, k = a.shape
     k2, n = w.shape
-    if k != k2 or block_size & (block_size - 1) or block_size > 128 \
-            or not 0 < mantissa_bits < 24:
-        raise ValueError(f"K={k} (weights {k2}), block_size {block_size}, "
-                         f"mantissa_bits {mantissa_bits}: need equal K, a "
-                         f"power-of-two block up to 128 and 1-23 bits")
+    if k != k2:
+        raise ValueError(f"K={k}, weights {k2}: need equal K")
     w = w.astype(jnp.float32)
-    if a.dtype == jnp.float16:
-        bits = jax.lax.bitcast_convert_type(a, jnp.int16)
-    else:
-        bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.int32)
+    bits = _as_bits(a)
     if k % 128:
         # the lanes a narrow K leaves empty in the tiled layout, as
         # zeros: they never win a block max and meet no weight row
@@ -257,8 +288,113 @@ def bfp_matmul_quantized(
         scratch_shapes=[pltpu.VMEM((bm, _lanes(k)), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=int(min(max(vmem + (16 << 20), 32 << 20),
-                                     100 << 20))),
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
     )(bits, w, bias)
+    return out if mp == m else out[:m]
+
+
+def _rows_kernel(a_ref, o_ref, *, block_size: int, mantissa_bits: int,
+                 rounding: str, rows: int, groups: int):
+    """a: (bm, C) int16/int32 bits; o: (bm, C) f32, the dequantized
+    values.  A C dividing 128 packs ``groups`` = 128 / C runs of
+    ``rows`` rows side by side into one 128-lane slab (lane rolls in and
+    out; blocks of at most C lanes never straddle two rows).  Any other
+    slab narrower than 128 lanes (C's last) is widened with zero lanes,
+    which never win a block max."""
+    c = a_ref.shape[1]
+    spread = _spread(block_size)
+    quantize = functools.partial(_quantize_slab, spread=spread,
+                                 block_size=block_size,
+                                 mantissa_bits=mantissa_bits,
+                                 rounding=rounding)
+
+    def widen(bits):
+        w = bits.shape[1]
+        if w == 128:
+            return bits
+        return jnp.concatenate(
+            [bits, jnp.zeros((bits.shape[0], 128 - w), jnp.int32)], axis=1)
+
+    def chunk(i, carry):
+        r = pl.multiple_of(i * rows * groups, rows * groups)
+        if groups > 1:
+            slab = None
+            for k in range(groups):
+                part = widen(_f32_bits(a_ref[pl.ds(r + k * rows, rows), :]))
+                part = pltpu.roll(part, k * c, 1) if k else part
+                slab = part if slab is None else slab | part
+            q = quantize(slab)
+            for k in range(groups):
+                qk = pltpu.roll(q, 128 - k * c, 1) if k else q
+                o_ref[pl.ds(r + k * rows, rows), :] = qk[:, :c]
+            return carry
+        for j in range(0, c, 128):
+            w = min(128, c - j)
+            q = quantize(widen(_f32_bits(a_ref[pl.ds(r, rows),
+                                               pl.ds(j, w)])))
+            o_ref[pl.ds(r, rows), pl.ds(j, w)] = q if w == 128 else q[:, :w]
+        return carry
+
+    jax.lax.fori_loop(0, a_ref.shape[0] // (rows * groups), chunk, 0)
+
+
+def _row_tiles(m: int, c: int, itemsize: int):
+    """(rows of A as called, bm, chunk rows, groups, vmem bytes): ``bm``
+    the row tile of ``_row_block`` whose double-buffered input and
+    output tiles fit the budget; ``groups`` runs of ``chunk rows`` share
+    a slab where C divides 128 and the tile holds whole sublane groups
+    of each."""
+    per_row = 2 * _lanes(c) * (itemsize + 4)
+    sub = 32 // itemsize
+    m, bm = _row_block(m, sub, _ROWS_CAP,
+                       lambda b: b * per_row <= _BLOCK_BUDGET)
+    groups = 128 // c if c < 128 and 128 % c == 0 else 1
+    if groups > 1 and bm % (groups * sub) == 0:
+        rows = min(bm // groups, _CHUNK_ROWS)
+    else:
+        groups = 1
+        rows = bm if bm % sub else min(bm, _CHUNK_ROWS)
+    return m, bm, rows, groups, bm * per_row
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "mantissa_bits", "rounding",
+                     "interpret"),
+)
+def bfp_roundtrip_rows(
+    a: jax.Array,                 # (M, C) f16 or f32 activation
+    *,
+    block_size: int,
+    mantissa_bits: int,
+    rounding: str = "trunc",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """(M, C) f32: Algorithm 1 along C in blocks of ``block_size`` (a
+    power of two up to 128; a C below it is one block), quantized and
+    dequantized in one pass: ``core.bfp.roundtrip(a, axis=-1)`` bit for
+    bit, f32 subnormal inputs aside (f16 storage never holds one)."""
+    if interpret is None:
+        interpret = repro.kernels.default_interpret()
+    _check(block_size, mantissa_bits, rounding)
+    m, c = a.shape
+    bits = _as_bits(a)
+    mp, bm, rows, groups, vmem = _row_tiles(m, c, bits.dtype.itemsize)
+    if mp != m:
+        bits = jnp.pad(bits, ((0, mp - m), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, mantissa_bits=mantissa_bits,
+                          block_size=min(block_size, c) if groups > 1
+                          else block_size,
+                          rounding=rounding, rows=rows, groups=groups),
+        grid=(mp // bm,),
+        in_specs=[pl.BlockSpec((bm, c), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem_limit(vmem)),
+        interpret=interpret,
+    )(bits)
     return out if mp == m else out[:m]

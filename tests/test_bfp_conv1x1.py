@@ -1,53 +1,80 @@
-"""The 1x1 BFP convs of a small bfp PixelLink on the fused kernel (one
-launch per word: in-kernel activation quantization, load-time weight
-matrices, bias and ReLU in the flush), against the round-trip path;
-raw weights through the in-call fallback; the kernel-word counter.
+"""The convs of small bfp PixelLinks (ResNet-50 and VGG-16 backbones)
+on the Pallas path: the 1x1s on the fused kernel (one launch per word:
+in-kernel activation quantization, load-time weight matrices, bias and
+ReLU in the flush), the others with their activation round trip in one
+pass and their load-time kernels as they are; against the jnp round-trip
+path; raw weights normalized in the call; the kernel-word counters.
 Pallas runs interpreted on the CPU."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.interpreter import MATRIX, BFPConfig, word_kind
+from repro.core.interpreter import (KERNEL, MATRIX, ROWS_MIN_CIN,
+                                    BFPConfig, word_kind)
 from repro.models.fcn.heads import DetectionModel, build_head
 from repro.models.fcn.pixellink import STDConfig
 
 
-def small_model(use_pallas):
-    cfg = STDConfig(name="small", backbone="resnet50", width=0.125,
-                    image_size=(32, 32), merge_ch=(16, 16, 8),
-                    upsample_mode="fused", bfp=BFPConfig(),
-                    storage_fp16=True, use_pallas=use_pallas)
-    return DetectionModel(cfg, build_head("pixellink"))
+BACKBONES = ["resnet50", "vgg16"]
+
+
+def small_config(backbone, **kw):
+    return STDConfig(name="small", backbone=backbone, width=0.125,
+                     image_size=(32, 32), merge_ch=(16, 16, 8),
+                     upsample_mode="fused", bfp=BFPConfig(),
+                     storage_fp16=True, **kw)
+
+
+def small_model(use_pallas, backbone="resnet50"):
+    return DetectionModel(small_config(backbone, use_pallas=use_pallas),
+                          build_head("pixellink"))
+
+
+def conv_words(model):
+    prog = model.program
+    return [(prog.words[i], word_kind(prog.words[i], prog.layer_specs[i]))
+            for i in prog.weight_bindings
+            if prog.layer_specs[i].op == "conv"]
 
 
 def n_1x1(model):
-    prog = model.program
-    return sum(word_kind(prog.words[i], prog.layer_specs[i]) == "conv1x1"
-               for i in prog.weight_bindings)
+    return sum(kind == "conv1x1" for _, kind in conv_words(model))
+
+
+def n_other(model):
+    """The conv words off the 1x1 kernel (3x3, strided)."""
+    return sum(kind != "conv1x1" for _, kind in conv_words(model))
 
 
 def unmarked(params):
-    """The normalized weights with each 1x1 matrix back under ``w``
-    as an HWIO kernel: BFP values the engine cannot tell from raw."""
+    """The normalized weights with each load-time form back under
+    ``w`` as an HWIO kernel: BFP values the engine cannot tell from
+    raw."""
     out = {}
     for name, p in params.items():
         p = dict(p)
         if MATRIX in p:
             p["w"] = p.pop(MATRIX)[None, None]
+        if KERNEL in p:
+            p["w"] = p.pop(KERNEL)
         out[name] = p
     return out
 
 
-@pytest.fixture(scope="module")
-def setup():
-    fused, plain = small_model(True), small_model(False)
+@pytest.fixture(scope="module", params=BACKBONES)
+def setup(request):
+    fused = small_model(True, request.param)
+    plain = small_model(False, request.param)
     raw = fused.init_params(jax.random.PRNGKey(0))
     x = jax.random.uniform(jax.random.PRNGKey(1), (1, 32, 32, 3))
     normed = jax.jit(fused.normalize_weights)(raw)
-    run = lambda m, p: jax.jit(m.apply)(p, x)
+    jitted = {id(m): jax.jit(m.apply) for m in (fused, plain)}
+    run = lambda m, p: jitted[id(m)](p, x)
     return dict(fused=fused, plain=plain, raw=raw, normed=normed, run=run,
-                out=run(fused, normed))
+                x=x, out=run(fused, normed), fused_fn=jitted[id(fused)])
 
 
 def test_normalize_weights_marks_every_1x1_word(setup):
@@ -56,6 +83,16 @@ def test_normalize_weights_marks_every_1x1_word(setup):
     assert len(marked) == n_1x1(model) > 0
     for name in marked:
         assert "w" not in normed[name] and normed[name][MATRIX].ndim == 2
+
+
+def test_normalize_weights_marks_every_other_conv_word(setup):
+    """3x3 and strided convs keep their HWIO kernel, BFP-normalized,
+    under a key of its own."""
+    normed, model = setup["normed"], setup["fused"]
+    marked = [n for n, p in normed.items() if KERNEL in p]
+    assert len(marked) == n_other(model) > 0
+    for name in marked:
+        assert "w" not in normed[name] and normed[name][KERNEL].ndim == 4
 
 
 def test_fused_path_matches_roundtrip_path(setup):
@@ -67,9 +104,9 @@ def test_fused_path_matches_roundtrip_path(setup):
 
 
 def test_raw_weights_take_the_fallback_with_the_same_answer(setup):
-    """The normalized weights handed over raw (each 1x1 kernel under
+    """The normalized weights handed over raw (each conv kernel under
     ``w``, unmarked) are normalized again in the call, which trunc
-    rounding leaves unchanged, and run the same kernel: the same maps
+    rounding leaves unchanged, and run the same kernels: the same maps
     bit for bit."""
     got = setup["run"](setup["fused"], unmarked(setup["normed"]))
     for key in ("score", "links"):
@@ -77,30 +114,62 @@ def test_raw_weights_take_the_fallback_with_the_same_answer(setup):
                                       np.asarray(setup["out"][key]))
 
 
+def n_rows(model):
+    """The conv words off the 1x1 kernel whose activation round trip the
+    one-pass kernel takes: those of ``ROWS_MIN_CIN`` input channels or
+    more (at width 1/8 some are narrower, and stay with XLA)."""
+    rows = sum(kind != "conv1x1" and mc.in_ch >= ROWS_MIN_CIN
+               for mc, kind in conv_words(model))
+    assert 0 < rows < n_other(model)
+    return rows
+
+
 def test_counter_reads_fused_or_fallback_words(setup):
-    fused, n = setup["fused"].engine, n_1x1(setup["fused"])
+    model = setup["fused"]
+    fused, n, rows = model.engine, n_1x1(model), n_rows(model)
     assert fused.kernel_words(setup["normed"]) == {
-        "bfp1x1_fused_words": n, "bfp1x1_fallback_words": 0}
+        "bfp1x1_fused_words": n, "bfp1x1_fallback_words": 0,
+        "bfp_rows_words": rows, "bfp_incall_weight_words": 0}
     for params in (setup["raw"], unmarked(setup["normed"])):
         assert fused.kernel_words(params) == {
-            "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": n}
-    # without the Pallas kernels no word runs on the BFP matmul kernel
-    assert setup["plain"].engine.kernel_words(setup["normed"]) == {
-        "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": 0}
+            "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": n,
+            "bfp_rows_words": rows,
+            "bfp_incall_weight_words": n_other(model)}
+    # without the Pallas kernels every conv takes the jnp round trip
+    plain = setup["plain"].engine
+    assert plain.kernel_words(setup["normed"]) == {
+        "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": 0,
+        "bfp_rows_words": 0, "bfp_incall_weight_words": 0}
+    assert plain.kernel_words(setup["raw"])[
+        "bfp_incall_weight_words"] == n + n_other(model)
 
 
-def test_service_books_the_counter_at_engine_build():
+def test_rows_kernel_runs_under_the_roundtrip_scope(setup):
+    """Each word the counter books calls the one-pass kernel once,
+    inside the word's ``bfp_roundtrip`` scope (what the benchmark's
+    quantize_share reads)."""
+    model = setup["fused"]
+    text = setup["fused_fn"].lower(setup["normed"], setup["x"]).as_text(
+        debug_info=True)
+    calls = set(re.findall(r'loc\("([^"]*)/jit\(bfp_roundtrip_rows\)"',
+                           text))
+    assert calls and all(re.fullmatch(
+        r"jit\(apply\)/w\d{3}\.(conv3x3|conv_strided)/bfp_roundtrip", c)
+        for c in calls), calls
+    assert len(calls) == n_rows(model)
+
+
+@pytest.mark.parametrize("backbone", BACKBONES)
+def test_service_books_the_counter_at_engine_build(backbone):
     from repro.launch.serve import STDService
 
-    cfg = STDConfig(name="small", backbone="resnet50", width=0.125,
-                    image_size=(32, 32), merge_ch=(16, 16, 8),
-                    upsample_mode="fused", bfp=BFPConfig(),
-                    storage_fp16=True)
-    svc = STDService(config=cfg, buckets=(32,), max_batch=1,
-                     precision="bfp")
+    svc = STDService(config=small_config(backbone), buckets=(32,),
+                     max_batch=1, precision="bfp")
     model = svc.factory.model((32, 32), "bfp")
     model.engine.use_pallas = True
     svc.infer_labels(np.zeros((1, 32, 32, 3), np.float32), [(32, 32)])
     snap = svc.metrics_snapshot()
     assert snap["std_bfp1x1_fused_words"] == n_1x1(model)
     assert snap["std_bfp1x1_fallback_words"] == 0
+    assert snap["std_bfp_rows_words"] == n_rows(model)
+    assert snap["std_bfp_incall_weight_words"] == 0

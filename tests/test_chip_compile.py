@@ -5,8 +5,8 @@ described rather than attached, so what the chip's compiler would refuse
 (block shapes off the (8, 128) tiling, unsupported in-kernel reshapes,
 fast memory over the scoped limit, kernels that cannot be partitioned)
 fails here at no chip time.  Each Pallas kernel of the served path
-compiles with ``interpret=False`` at ResNet-50 512x512 shapes, and one
-whole f32 engine step compiles with the Pallas CC tail.
+compiles with ``interpret=False`` at ResNet-50 and VGG-16 512x512
+shapes, and one whole f32 engine step compiles with the Pallas CC tail.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -73,6 +73,14 @@ BFP = [(4096, 1024, 256), (131072, 64, 256), (8192, 2048, 512),
 # load-time f32 weights (K, N), bias and ReLU in the flush
 FUSED_1X1 = [(131072, 64, 256), (32768, 1152, 128), (8192, 2048, 9),
              (131072, 288, 32), (2048, 512, 2048)]
+# the one-pass activation round trip before the convs off the matmul
+# kernel, (batch, H, W, Cin) as the engine reshapes them to (M, Cin) rows:
+# VGG-16's batch-8 3x3 inputs at 512, 256, 128 and 32, the merge's
+# 32-channel 3x3 input, and ResNet-50's strided inputs (3x3 stride 2 at
+# 128 and 64, 1x1 stride-2 projections at 128, 64 and 32)
+ROWS = [(8, 512, 512, 64), (8, 256, 256, 128), (8, 128, 128, 256),
+        (8, 32, 32, 512), (8, 128, 128, 32), (8, 128, 128, 128),
+        (8, 64, 64, 256), (8, 64, 64, 512), (8, 32, 32, 1024)]
 # (batch, h, w) label planes: 128 wide (512 bucket), 64 (256 bucket),
 # and a 160-wide plane that pads to two 128-lane tiles
 CC = [(8, 128, 128), (8, 64, 64), (2, 128, 160)]
@@ -116,6 +124,19 @@ def test_fused_bfp_conv1x1_compiles(one_chip, no_cache, shape):
             a, w, b, block_size=32, mantissa_bits=10, relu=True,
             interpret=False),
         ((m, k), jnp.float16), ((k, n), jnp.float32), ((n,), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("shape", ROWS, ids=str)
+def test_bfp_roundtrip_rows_compiles(one_chip, no_cache, shape):
+    from repro.kernels.bfp_matmul.kernel import bfp_roundtrip_rows
+
+    text = _compile_text(
+        one_chip,
+        lambda x: bfp_roundtrip_rows(
+            x.reshape(-1, x.shape[-1]), block_size=32, mantissa_bits=10,
+            interpret=False).reshape(x.shape),
+        (shape, jnp.float16))
     assert text.count("tpu_custom_call") == 1
 
 

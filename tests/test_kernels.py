@@ -105,6 +105,34 @@ class TestFusedBFPMatmulKernel:
         np.testing.assert_array_equal(np.asarray(got, np.float64), want)
 
 
+class TestBFPRoundtripRows:
+    """The one-pass activation round trip of the convs off the matmul
+    kernel: (M, C) rows quantized and dequantized along C."""
+
+    @pytest.mark.parametrize("m", [1, 37, 300])
+    @pytest.mark.parametrize("c", [3, 20, 32, 48, 64, 256, 512])
+    @pytest.mark.parametrize("dtype", [jnp.float16, jnp.float32])
+    @pytest.mark.parametrize("rounding", ["trunc", "nearest"])
+    def test_bit_identical_to_roundtrip(self, m, c, dtype, rounding):
+        """Bit for bit ``core.bfp.roundtrip(a, axis=-1)`` on served
+        activations (all-zero blocks, negatives, f16 subnormals), for C
+        below one block, between blocks, dividing 128 (rows packed into
+        one slab) and over several 128-lane slabs, and row counts of no
+        power-of-two tile (one row; one block of 37 rows; 300 rows padded
+        to a tile multiple)."""
+        from repro.core import bfp
+        from repro.kernels.bfp_matmul.kernel import bfp_roundtrip_rows
+
+        a = _served_activation(m, c, c + m).astype(dtype)
+        got = bfp_roundtrip_rows(a, block_size=32, mantissa_bits=10,
+                                 rounding=rounding, interpret=True)
+        want = bfp.roundtrip(a.astype(jnp.float32), block_size=32,
+                             mantissa_bits=10, axis=-1, rounding=rounding)
+        assert got.shape == (m, c) and got.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                      np.asarray(want).view(np.int32))
+
+
 class TestWinogradKernel:
     @settings(max_examples=10, deadline=None)
     @given(
