@@ -48,16 +48,6 @@ within an eps accuracy budget of f32 AND the recovered boxes match
 exactly once pixels inside the eps margin of the 0.5 threshold are
 excluded — confident disagreements fail the run.
 
-memplan A/B (``--memplan``) — the memory-planner sweep: a memplan-off
-service at the fixed ``--max-batch`` vs a memplan-on service whose
-``activation_budget_bytes`` is sized from the largest bucket's planned
-peak (core/memplan.py) so that bucket's admissible batch caps below the
-fixed max while a smaller bucket is admitted above it.  Gates: EXACT
-box parity over the model x plan x precision matrix, >= 20% measured
-temp-bytes reduction (AOT buffer assignment, hlo_analysis) on the
-largest bucket, and at least one raised cap; reports planned-vs-
-measured bytes, per-bucket caps, and serve_batched TPS for both sides.
-
 fleet A/B (``--replicas N --router round_robin p99 least_loaded``) —
 the pod-scale sweep: N replicated services, each with its own
 replica-labelled CostBook, behind a launch/router.Router; ONE seeded
@@ -93,10 +83,6 @@ Run:  PYTHONPATH=src python -m benchmarks.serve_bench --requests 32
           --buckets 64 --width 0.125 --max-batch 4
       PYTHONPATH=src python -m benchmarks.serve_bench --replicas 2 \
           --router round_robin p99 --buckets 64 --width 0.125
-      PYTHONPATH=src python -m benchmarks.serve_bench --memplan \
-          --width 0.125 --buckets 64 128 --max-batch 4 \
-          --model pixellink --memplan-plans single \
-          --memplan-precisions f32
 """
 from __future__ import annotations
 
@@ -661,183 +647,6 @@ def run_model_zoo(models, *, requests: int = 8, width: float = 0.25,
     return out
 
 
-def run_memplan_ab(*, width: float = 0.25, buckets=(64, 128),
-                   max_batch: int = 8, requests: int = 16,
-                   max_wait_ms: float = 8.0, seed: int = 0,
-                   models=("pixellink", "east", "db"),
-                   plans=("single", "data", "rowband", "grid"),
-                   precisions=("f32", "bfp"),
-                   parity_images: int = 2, min_reduction: float = 0.2,
-                   pre_workers: int = 4, verbose: bool = True):
-    """Memory-planner A/B (docs/plans.md "Memory planning").
-
-    XLA already schedules buffers liveness-optimally inside one engine,
-    so the plan's lever on MEASURED memory is batching: the memplan-on
-    service gets an ``activation_budget_bytes`` sized so the largest
-    bucket's admissible batch (budget // planned-peak-per-image, the
-    core.memplan ``admissible_batch`` rule) lands BELOW the fixed
-    ``--max-batch`` while smaller buckets — smaller footprints — are
-    admitted ABOVE it.  Per-image boxes are batch-invariant, so this is
-    free of accuracy cost, and the run proves both halves:
-
-      parity — memplan-on vs memplan-off services (same PRNGKey(0)
-      weights) must produce EXACTLY equal box sets for every request
-      across the full ``models`` x ``plans`` x ``precisions`` matrix
-      (the planned schedule, fusion facts, and drop-at-last-use must
-      not change a single output);
-
-      memory — on the LARGEST bucket, the memplan-on engine's measured
-      temp bytes (AOT buffer assignment via hlo_analysis) must be at
-      least ``min_reduction`` below the memplan-off engine's at the
-      fixed max batch, while at least one smaller bucket's admissible
-      cap exceeds ``--max-batch`` (the throughput the planner buys
-      back with the bytes it saved).
-
-    A closing ``serve_batched`` pass on one seeded stream reports TPS
-    for both services — the caps must also hold up under the live
-    scheduler, not just in the gauge math."""
-    from repro.data.images import RequestStream
-    from repro.launch.serve import STDService
-    from repro.runtime.telemetry import CostBook
-
-    if requests < 1:
-        raise SystemExit("--requests must be >= 1")
-    if max_batch < 2:
-        raise SystemExit("--max-batch must be >= 2 so the budget can cap "
-                         "the largest bucket strictly below it")
-    buckets = tuple(sorted(set(buckets)))
-    if len(buckets) < 2:
-        raise SystemExit("--memplan needs >= 2 buckets: the A/B shows the "
-                         "largest capped below --max-batch AND a smaller "
-                         "one admitted above it")
-    models = list(dict.fromkeys(models))
-    plans = list(dict.fromkeys(plans))
-    precisions = list(dict.fromkeys(precisions))
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name in models:
-        # -- budget: cap the largest bucket at ~half the fixed max batch
-        probe = STDService(width=width, buckets=buckets,
-                           max_batch=max_batch, engine_cache_capacity=0,
-                           book=CostBook(warmup=0), model=name)
-        big = (buckets[-1], buckets[-1])
-        peak_img = int(probe.factory.memplan(big, "f32", name).peak_bytes)
-        cap_target = max(1, max_batch // 2)
-        budget = peak_img * cap_target
-        svc_pair = {}          # the single/f32 pair the memory gate reads
-        parity = {}
-        for plan_kind in plans:
-            kw, _, bkts = _plan_setup(plan_kind, None, buckets, max_batch)
-            for prec in precisions:
-                mk = lambda on: STDService(
-                    width=width, buckets=bkts, max_batch=max_batch,
-                    max_wait_ms=max_wait_ms, engine_cache_capacity=0,
-                    book=CostBook(warmup=0), model=name, precision=prec,
-                    memplan=on,
-                    activation_budget_bytes=budget if on else None,
-                    **kw)
-                svcs = {"off": mk(False), "on": mk(True)}
-                if plan_kind == "single" and prec == "f32":
-                    svc_pair = dict(svcs)
-                lo = 48
-                for bkt in bkts:
-                    hw = (max(lo, bkt - 5), max(lo, bkt - 7))
-                    lo = bkt + 1
-                    n_ok = 0
-                    for _ in range(parity_images):
-                        img = (rng.random((hw[0], hw[1], 3)) * 255.0
-                               ).astype(np.float32)
-                        got = {
-                            side: sorted(b["box"] for b in svc(img))
-                            for side, svc in svcs.items()
-                        }
-                        n_ok += got["on"] == got["off"]
-                    parity[(plan_kind, prec, bkt)] = (n_ok, parity_images)
-                    if verbose:
-                        print(f"memplan_parity,model={name},"
-                              f"plan={plan_kind},precision={prec},"
-                              f"bucket={bkt}x{bkt},"
-                              f"boxes_equal={n_ok}/{parity_images}")
-                    if n_ok != parity_images:
-                        raise SystemExit(
-                            f"memplan parity FAILED for {name!r} "
-                            f"plan={plan_kind} precision={prec} at bucket "
-                            f"{bkt}: {parity_images - n_ok}/{parity_images}"
-                            f" requests' boxes diverge between the "
-                            f"planned and unplanned engines"
-                        )
-        # -- admissible-batch caps: largest below max_batch, some bucket
-        # above it (svc_pair exists: plans/precisions are non-empty and
-        # the single/f32 combo is required for the gate)
-        if "on" not in svc_pair:
-            raise SystemExit("--memplan-plans must include 'single' and "
-                             "--memplan-precisions 'f32' (the memory gate "
-                             "measures that pair)")
-        svc_on, svc_off = svc_pair["on"], svc_pair["off"]
-        caps = {(b, b): svc_on._bucket_cap((b, b)) for b in buckets}
-        for hw, cap in sorted(caps.items()):
-            if verbose:
-                print(f"memplan_cap,model={name},bucket={hw[0]}x{hw[1]},"
-                      f"cap={cap},max_batch={max_batch}")
-        if caps[big] >= max_batch:
-            raise SystemExit(
-                f"memplan cap at the largest bucket {big} is {caps[big]} "
-                f">= --max-batch {max_batch}; the budget failed to bind"
-            )
-        if not any(c > max_batch for c in caps.values()):
-            raise SystemExit(
-                f"no bucket's admissible batch exceeds --max-batch "
-                f"{max_batch} under budget {budget} — caps {caps}"
-            )
-        # -- measured memory on the largest bucket: off at the fixed max
-        # batch vs on at its capped batch
-        rows = {side: svc.measure_engine_memory(big)
-                for side, svc in (("off", svc_off), ("on", svc_on))}
-        reduction = 1.0 - (rows["on"]["temp_bytes"]
-                           / max(rows["off"]["temp_bytes"], 1))
-        if verbose:
-            print(f"memplan_mem,model={name},bucket={big[0]}x{big[1]},"
-                  f"batch_off={rows['off']['batch']},"
-                  f"temp_off={rows['off']['temp_bytes']},"
-                  f"batch_on={rows['on']['batch']},"
-                  f"temp_on={rows['on']['temp_bytes']},"
-                  f"planned_on={rows['on']['planned_peak_bytes']},"
-                  f"reduction={reduction:.2f}")
-        if reduction < min_reduction:
-            raise SystemExit(
-                f"memplan memory gate FAILED for {name!r}: temp bytes "
-                f"reduction {reduction:.2f} < {min_reduction} at bucket "
-                f"{big} ({rows['off']['temp_bytes']} -> "
-                f"{rows['on']['temp_bytes']})"
-            )
-        # -- serving smoke: the caps must hold under the live scheduler
-        images = RequestStream(
-            requests, seed=seed,
-            hw_range=((48, buckets[-1]), (48, buckets[-1])),
-        ).images()
-        tps = {}
-        for side, svc in (("off", svc_off), ("on", svc_on)):
-            svc.serve_batched(images, pre_workers=pre_workers)
-            tps[side] = svc.stats["batched_tps"]
-        n_caps = sum(1 for k in svc_on.metrics_snapshot()
-                     if k.startswith("std_bucket_batch_cap"))
-        if verbose:
-            print(f"memplan_serve,model={name},"
-                  f"tps_off {tps['off']:.2f},tps_on {tps['on']:.2f},"
-                  f"cap_gauges={n_caps}")
-        out[name] = {
-            "budget_bytes": budget,
-            "caps": {f"{h}x{w}": c for (h, w), c in sorted(caps.items())},
-            "parity": {f"{p}/{pr}/{b}": v
-                       for (p, pr, b), v in sorted(parity.items())},
-            "temp_bytes": {s: rows[s]["temp_bytes"] for s in rows},
-            "planned_peak_bytes": rows["on"]["planned_peak_bytes"],
-            "reduction": reduction,
-            "tps": tps,
-        }
-    return out
-
-
 def run_fleet_ab(policies, *, replicas: int = 2, requests: int = 16,
                  width: float = 0.25, buckets=(64,), max_batch: int = 4,
                  max_wait_ms: float = 8.0, seed: int = 0,
@@ -1245,40 +1054,10 @@ def main(argv=None):
                          "seeded stream (exact box parity per bucket), "
                          "then smoke-serve the stream through its "
                          "compiled engines")
-    ap.add_argument("--memplan", action="store_true",
-                    help="memory-planner A/B ONLY: memplan-on vs "
-                         "memplan-off services — exact box parity over "
-                         "the model x plan x precision matrix, measured "
-                         "temp-bytes reduction >= 20%% on the largest "
-                         "bucket, and a smaller bucket admitted above "
-                         "--max-batch (restrict the matrix with --model/"
-                         "--memplan-plans/--memplan-precisions)")
-    ap.add_argument("--memplan-plans", nargs="+",
-                    default=["single", "data", "rowband", "grid"],
-                    choices=["single", "data", "rowband", "grid"],
-                    help="plan kinds the --memplan parity matrix covers "
-                         "(must include 'single': the memory gate "
-                         "measures the single/f32 pair)")
-    ap.add_argument("--memplan-precisions", nargs="+",
-                    default=["f32", "bfp"], choices=["f32", "bfp"],
-                    help="precisions the --memplan parity matrix covers "
-                         "(must include 'f32')")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import use_compile_cache
 
     use_compile_cache()
-    if args.memplan:
-        return run_memplan_ab(width=args.width,
-                              buckets=tuple(args.buckets),
-                              max_batch=args.max_batch,
-                              requests=args.requests,
-                              max_wait_ms=args.max_wait_ms,
-                              seed=args.seed,
-                              models=tuple(args.model
-                                           or ("pixellink", "east", "db")),
-                              plans=tuple(args.memplan_plans),
-                              precisions=tuple(args.memplan_precisions),
-                              pre_workers=args.pre_workers)
     if args.replicas:
         return run_fleet_ab(args.router,
                             replicas=args.replicas,

@@ -65,8 +65,8 @@ PYEOF
 echo "== tier-2: precision modes — bfp-vs-f32 box parity + engine-state regressions =="
 # The bfp-vs-f32 accuracy-parity smoke (0.5-threshold guard on the
 # bucket grid), the per-precision engine LRU keying, the concurrent
-# transposed-tracing regression, and the in-call BFP weight
-# quantization regression all live in test_precision.py; the kernel
+# transposed-tracing regression, and the raw-weight refusal of BFP
+# engines all live in test_precision.py; the kernel
 # interpret-default regressions ride along.  These also run in the fast
 # tiers — this stage keeps them failing loudly when CI is invoked with
 # path args that skip the fast tiers.
@@ -109,19 +109,12 @@ python -m benchmarks.serve_bench --replicas 2 \
   --router round_robin p99 \
   --width 0.125 --buckets 64 --max-batch 2 --requests 8
 
-echo "== tier-2: memplan — static memory planner suite + serve_bench --memplan smoke =="
+echo "== tier-2: memplan — static memory planner suite =="
 # The static microcode optimizer / data-pool memory planner: liveness,
 # dead-word/dead-store elimination, arena slot accounting, the
-# byte-weighted engine LRU, per-bucket batch caps, memplan golden
-# snapshots (--check above already gates them), and a tiny
-# serve_bench --memplan A/B — the run itself FAILS unless the planned
-# budget caps the largest bucket below --max-batch, a smaller bucket
-# is admitted above it, measured temp bytes drop >= 20% on the largest
-# bucket, and memplan-on/off boxes match exactly.
+# byte-weighted engine LRU, per-bucket batch caps, and memplan golden
+# snapshots (--check above already gates them).
 python -m pytest -q tests/test_memplan.py
-python -m benchmarks.serve_bench --memplan \
-  --width 0.125 --buckets 64 128 --max-batch 4 --requests 6 \
-  --model pixellink --memplan-plans single --memplan-precisions f32
 
 echo "== tier-2: slow distributed/serving tests on a multi-device host mesh =="
 # The pytest process itself sees 8 host CPU devices, activating any

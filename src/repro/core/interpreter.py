@@ -13,9 +13,10 @@ fields; the datapath units are jnp/Pallas implementations chosen by
                       available
 
 BFP numerics (paper §III.E): when a :class:`BFPConfig` is given, conv
-inputs and weights are run through Algorithm 1 quantization before the MAC
-and the accumulator stays wide (f32 >= the paper's 15-bit mantissa) — the
-§IV.C accuracy-maintenance discipline.  Storage between layers is FP16
+weights are run through Algorithm 1 quantization once, at load time
+(:meth:`FCNEngine.normalize_weights`), conv inputs in every call before the
+MAC, and the accumulator stays wide (f32 >= the paper's 15-bit mantissa) —
+the §IV.C accuracy-maintenance discipline.  Storage between layers is FP16
 (``storage_dtype``), exactly the paper's data-pool format.
 
 The same interpreter executes LM architectures: :func:`build_stream_fn`
@@ -27,7 +28,7 @@ the transformer residual is *literally* the paper's Fig. 3 mechanism.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +38,7 @@ from jax import lax
 from . import bfp as bfp_lib
 from . import fuse, winograd
 from .assembler import Program, STORAGE_BYTES
+from .memplan import plan_program
 from .microcode import ExtOp, LayerType, Microcode, ResOp
 
 
@@ -80,6 +82,13 @@ KERNEL = "w_bfp"
 ROWS_MIN_CIN = 32
 
 
+def weight_key(mc: Microcode, spec) -> str:
+    """The key a conv word's load-time weights sit under on a BFP engine:
+    ``MATRIX`` for a ``conv1x1`` word (a matmul), ``KERNEL`` for every
+    other conv."""
+    return MATRIX if word_kind(mc, spec) == "conv1x1" else KERNEL
+
+
 def _he_init(key, shape, fan_in):
     return jax.random.normal(key, shape, jnp.float32) * np.sqrt(2.0 / fan_in)
 
@@ -94,7 +103,6 @@ class FCNEngine:
         bfp: Optional[BFPConfig] = None,
         storage_dtype=jnp.float32,
         use_pallas: bool = False,
-        memplan=None,
     ):
         if mode not in ("reference", "optimized"):
             raise ValueError(mode)
@@ -103,19 +111,13 @@ class FCNEngine:
         self.bfp = bfp
         self.storage_dtype = storage_dtype
         self.use_pallas = use_pallas
-        # memplan: None/False -> legacy keep-everything loop; True ->
-        # compute the static plan here (once per engine, pure function of
-        # the program); a MemPlan instance is used as-is.  The plan
-        # supplies fusion facts, dead-word/dead-store elimination, and
-        # per-word free-after sets so the trace drops a buffer reference
+        # the static memory plan (core.memplan, a pure function of the
+        # program): the live words, fusion facts, dead stores and
+        # per-word free-after sets, so the trace drops a buffer reference
         # at its last use instead of pinning every intermediate.
-        if memplan is True:
-            from . import memplan as memplan_lib
-
-            memplan = memplan_lib.plan_program(
-                program, dtype_bytes=jnp.dtype(storage_dtype).itemsize
-            )
-        self.memplan = memplan or None
+        # memplan.identity_plan runs every word and keeps everything.
+        self.memplan = plan_program(
+            program, dtype_bytes=jnp.dtype(storage_dtype).itemsize)
 
     # -- parameters ----------------------------------------------------------
     def init_params(self, key: jax.Array) -> Dict[str, Dict[str, jax.Array]]:
@@ -158,10 +160,13 @@ class FCNEngine:
         return params
 
     def normalize_weights(self, params):
-        """Paper Fig. 4 right branch: fold BN, then BFP-normalize weights."""
+        """Paper Fig. 4 right branch: fold BN, then BFP-normalize weights.
+        A BFP engine's conv words read their weights only in this form,
+        under :func:`weight_key`; f32 engines and upsample words keep
+        ``w``."""
         out = {}
         for idx, name in self.program.weight_bindings.items():
-            spec = self.program.layer_specs[idx]
+            mc, spec = self.program.words[idx], self.program.layer_specs[idx]
             p = dict(params[name])
             if spec.op == "conv" and spec.bn:
                 w, b = fuse.fold_batchnorm(
@@ -170,62 +175,44 @@ class FCNEngine:
                 )
                 p = {"w": w, "b": b}
             if self.bfp is not None and "w" in p:
-                w = self._bfp_weights(p.pop("w"))
-                if spec.op != "conv":
-                    p["w"] = w
-                elif word_kind(self.program.words[idx], spec) == "conv1x1":
-                    # a 1x1 conv is a matmul: its (Cin, Cout) matrix,
-                    # marked by its key, is what the BFP matmul kernel
-                    # takes as it is
-                    p[MATRIX] = w.reshape(w.shape[-2:])
-                else:
-                    p[KERNEL] = w
+                # Algorithm 1 on the kernel, blocked along Cin (the K dim)
+                w = bfp_lib.roundtrip(
+                    p.pop("w").astype(jnp.float32),
+                    block_size=self.bfp.block_size,
+                    mantissa_bits=self.bfp.mantissa_bits,
+                    axis=-2,
+                    rounding=self.bfp.rounding,
+                )
+                key = weight_key(mc, spec) if spec.op == "conv" else "w"
+                # a 1x1 conv is a matmul: its (Cin, Cout) matrix is what
+                # the BFP matmul kernel takes as it is
+                p[key] = w.reshape(w.shape[-2:]) if key == MATRIX else w
             out[name] = p
         return out
 
-    def _bfp_weights(self, w):
-        """Algorithm 1 on a conv kernel, blocked along Cin (the K dim)."""
-        return bfp_lib.roundtrip(
-            w.astype(jnp.float32),
-            block_size=self.bfp.block_size,
-            mantissa_bits=self.bfp.mantissa_bits,
-            axis=-2,
-            rounding=self.bfp.rounding,
-        )
-
-    def kernel_words(self, params) -> Dict[str, int]:
-        """How the conv words of a BFP engine run on ``params``.  The 1x1
-        words on the BFP matmul kernel: on load-time matrices
-        (``bfp1x1_fused_words``) or on raw weights quantized in the call
-        (``bfp1x1_fallback_words``).  The other conv words: those whose
-        activation round trip runs as one Pallas pass
-        (``bfp_rows_words``), and those whose raw weights are quantized
-        in every call (``bfp_incall_weight_words``)."""
-        fused = fallback = rows = incall = 0
-        for idx, name in self.program.weight_bindings.items():
+    def kernel_words(self) -> Dict[str, int]:
+        """How the conv words of a BFP engine run: the 1x1 words on the
+        BFP matmul kernel (``bfp1x1_fused_words``), and the other conv
+        words whose activation round trip runs as one Pallas pass
+        (``bfp_rows_words``)."""
+        fused = rows = 0
+        for idx in self.program.weight_bindings:
             mc, spec = self.program.words[idx], self.program.layer_specs[idx]
-            if self.bfp is None or spec.op != "conv":
+            if spec.op != "conv":
                 continue
-            raw = "w" in params.get(name, {})
-            if self._bfp_matmul(mc, spec):
-                fused += not raw
-                fallback += raw
-            else:
-                rows += self._bfp_rows(mc, spec)
-                incall += raw
-        return {"bfp1x1_fused_words": fused,
-                "bfp1x1_fallback_words": fallback,
-                "bfp_rows_words": rows,
-                "bfp_incall_weight_words": incall}
+            fused += self._bfp_matmul(mc, spec)
+            rows += self._bfp_rows(mc, spec)
+        return {"bfp1x1_fused_words": fused, "bfp_rows_words": rows}
 
     def _pallas_bfp(self) -> bool:
         return (self.bfp is not None and self.use_pallas
                 and self.mode == "optimized")
 
     def _bfp_matmul(self, mc: Microcode, spec) -> bool:
-        """Whether a word runs on the BFP matmul kernel: a 1x1 stride-1
-        conv of an optimized BFP engine with the Pallas kernels on."""
-        return self._pallas_bfp() and word_kind(mc, spec) == "conv1x1"
+        """Whether a word runs on the BFP matmul kernel: a conv whose
+        load-time weights are a ``MATRIX`` (1x1, stride 1), on an
+        optimized BFP engine with the Pallas kernels on."""
+        return self._pallas_bfp() and weight_key(mc, spec) == MATRIX
 
     def _bfp_rows(self, mc: Microcode, spec) -> bool:
         """Whether a conv word off the BFP matmul kernel round-trips its
@@ -245,28 +232,32 @@ class FCNEngine:
         explicit argument — never instance state, so concurrent traces
         of one cached engine (transposed vs not, PR 4's async dispatch)
         each bake their own kernel orientation.  ``relu=True`` fuses the
-        word's activation into this launch (fuse.can_fuse_conv_epilogue
-        decides eligibility at the call site)."""
+        word's activation into this launch (the memory plan decides
+        eligibility).  A BFP engine reads the weights only in their
+        load-time form, under :func:`weight_key`."""
         b = p.get("b")
+        if self.bfp is None:
+            w = p["w"]
+        else:
+            key = weight_key(mc, spec)
+            if key not in p:
+                raise ValueError(
+                    f"a BFP engine reads {word_kind(mc, spec)} weights only "
+                    f"in their load-time form {key!r}; run the parameters "
+                    f"through normalize_weights first")
+            w = p[key]
         if self._bfp_matmul(mc, spec):
             # a 1x1 conv IS a matmul: one BFP kernel launch quantizes the
             # f16 activation along Cin in VMEM, multiplies by the
-            # load-time matrix and applies bias and ReLU in its flush;
-            # raw weights are normalized here first (the Fig. 4 branch
-            # must hold whether or not normalize_weights() ran offline)
+            # load-time matrix and applies bias and ReLU in its flush
             from repro.kernels.bfp_matmul.kernel import bfp_matmul_quantized
 
-            wm = p.get(MATRIX)
-            if wm is None:
-                with jax.named_scope("bfp_roundtrip"):
-                    wm = self._bfp_weights(p["w"]).reshape(
-                        p["w"].shape[-2:])
             n, hh, ww, cin = x.shape
             # the views in and out of the kernel: scope bfp_matmul_io
             with jax.named_scope("bfp_matmul_io"):
                 xm = x.reshape(-1, cin)
             y = bfp_matmul_quantized(
-                xm, wm, b,
+                xm, w, b,
                 block_size=self.bfp.block_size,
                 mantissa_bits=self.bfp.mantissa_bits,
                 rounding=self.bfp.rounding,
@@ -274,12 +265,8 @@ class FCNEngine:
             )
             with jax.named_scope("bfp_matmul_io"):
                 return y.reshape(n, hh, ww, -1)
-        # load-time weights (normalize_weights) are used as they are;
-        # raw ones are normalized in the call, so the Fig. 4 branch holds
-        # whether or not normalize_weights() ran offline
-        raw = "w" in p
-        w = p["w"] if raw else p.get(KERNEL, p.get(MATRIX))
         if w.ndim == 2:
+            # a MATRIX off the matmul kernel: the 1x1 kernel it was
             w = w[None, None]
         if transposed:
             # transposed-image mode: transpose the weight kernels (paper:
@@ -288,7 +275,7 @@ class FCNEngine:
             w = jnp.swapaxes(w, 0, 1)
         depthwise = bool(spec.table and spec.table.get("depthwise"))
         if self.bfp is not None:
-            # Algorithm 1 on both operands: scope bfp_roundtrip (what the
+            # Algorithm 1 on the activation: scope bfp_roundtrip (what the
             # benchmark's quantize_share reads)
             with jax.named_scope("bfp_roundtrip"):
                 if self._bfp_rows(mc, spec):
@@ -309,8 +296,6 @@ class FCNEngine:
                         axis=-1,
                         rounding=self.bfp.rounding,
                     )
-                if raw:
-                    w = self._bfp_weights(w)
         x = x.astype(jnp.float32)
         w = w.astype(jnp.float32)
         if depthwise:
@@ -356,12 +341,9 @@ class FCNEngine:
             y = y / (k * k)
         return y
 
-    def _upsample(self, x, p, mc, spec, *, decomposed: Optional[bool] = None):
+    def _upsample(self, x, p, *, decomposed: bool):
         # ``decomposed`` is the plan fact "this upsample carries a 3x3
-        # conv eligible for phase decomposition"; None derives it from
-        # the spec (legacy no-plan path).
-        if decomposed is None:
-            decomposed = spec.upsample_mode != "nearest"
+        # conv eligible for phase decomposition"
         if not decomposed:
             return fuse.upsample_nearest_2x(x)
         w = p["w"].astype(jnp.float32)
@@ -449,12 +431,11 @@ class FCNEngine:
             return jnp.concatenate(parts, axis=-1)
 
         plan = self.memplan
-        indices = plan.schedule if plan is not None else range(len(prog.words))
-        for idx in indices:
+        for idx in plan.schedule:
             mc = prog.words[idx]
             spec = prog.layer_specs[idx]
             with jax.named_scope(f"w{idx:03d}.{word_kind(mc, spec)}"):
-                wp = plan.word(idx) if plan is not None else None
+                wp = plan.word(idx)
                 xin = read(mc.in_addr, mc.in_ch)
                 name = prog.weight_bindings.get(idx)
                 p = params.get(name, {}) if name else {}
@@ -462,13 +443,10 @@ class FCNEngine:
                 fused_relu = False
                 if lt == LayerType.CONV:
                     # conv+bias+ReLU fuse into one launch (optimized
-                    # mode; eligibility is a plan fact when a memplan is
-                    # bound, the per-call fuse.py check otherwise — the
-                    # residual register reads the pre-activation value,
-                    # so res words keep a separate ReLU either way)
-                    eligible = (wp.fuse_relu if wp is not None
-                                else fuse.can_fuse_conv_epilogue(mc))
-                    fused_relu = self.mode == "optimized" and eligible
+                    # mode, where the plan says so — the residual
+                    # register reads the pre-activation value, so res
+                    # words keep a separate ReLU)
+                    fused_relu = self.mode == "optimized" and wp.fuse_relu
                     y = self._spatial_banded(
                         band_ctx, xin, mc.kernel_size, mc.stride_n,
                         lambda xb: self._conv(xb, p, mc, spec,
@@ -482,13 +460,11 @@ class FCNEngine:
                         lambda xb: self._pool(xb, mc, spec),
                     )
                 elif lt == LayerType.UPSAMPLE:
-                    up_conv = (wp.fuse_upsample if wp is not None
-                               else spec.upsample_mode != "nearest")
+                    up_conv = wp.fuse_upsample
                     y = self._spatial_banded(
                         band_ctx, xin,
                         3 if up_conv else 1, 1,
-                        lambda xb: self._upsample(xb, p, mc, spec,
-                                                  decomposed=up_conv),
+                        lambda xb: self._upsample(xb, p, decomposed=up_conv),
                         out_scale=2,
                     )
                 else:
@@ -515,18 +491,17 @@ class FCNEngine:
                 # write back to the data pool in storage precision (FP16
                 # in the paper; f32 for the reference numerics)
                 y = y.astype(self.storage_dtype)
-                if wp is None or wp.store:
+                if wp.store:
                     arena[mc.out_addr] = y
                     h, w, c = prog.addr_shapes[mc.out_addr]
                     extents[mc.out_addr] = h * w * c * STORAGE_BYTES
-                if wp is not None:
-                    # drop buffers at their last use so the trace holds no
-                    # reference past the plan's liveness range
-                    for a in wp.free_after:
-                        arena.pop(a, None)
-                        extents.pop(a, None)
-                    if wp.drop_cache:
-                        cache = None
+                # drop buffers at their last use so the trace holds no
+                # reference past the plan's liveness range
+                for a in wp.free_after:
+                    arena.pop(a, None)
+                    extents.pop(a, None)
+                if wp.drop_cache:
+                    cache = None
 
         return {k: arena[a] for k, a in prog.outputs.items()}
 

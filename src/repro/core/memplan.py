@@ -134,7 +134,7 @@ def plan_program(program: Program, *, dtype_bytes: int = 4) -> MemPlan:
     # freed — rather than a wrong one.
     out_addrs = [mc.out_addr for mc in words]
     if len(set(out_addrs)) != n:
-        return _identity_plan(program, dtype_bytes)
+        return identity_plan(program, dtype_bytes)
 
     def_word: Dict[int, int] = {program.input_addr: -1}
     for i, mc in enumerate(words):
@@ -299,8 +299,9 @@ def plan_program(program: Program, *, dtype_bytes: int = 4) -> MemPlan:
     )
 
 
-def _identity_plan(program: Program, dtype_bytes: int) -> MemPlan:
-    """Conservative fallback: run every word, free nothing."""
+def identity_plan(program: Program, dtype_bytes: int) -> MemPlan:
+    """Run every word, store every output, free nothing: the conservative
+    fallback, and the keep-everything run an engine can be handed."""
     words = program.words
     n = len(words)
     naive = sum(

@@ -7,8 +7,7 @@ op (per-device bytes, since SPMD HLO shapes are per-device).
 
 :func:`memory_stats` / :func:`lowered_memory` read the backend's buffer
 assignment off a compiled executable (``memory_analysis()``) — the
-ground truth the memplan peak-bytes prediction and the serve_bench
---memplan A/B are judged against.
+ground truth the memplan peak-bytes prediction is judged against.
 """
 from __future__ import annotations
 

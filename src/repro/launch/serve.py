@@ -128,7 +128,6 @@ class STDService:
                  postprocess: str = "host",
                  boxes_capacity: int = 256,
                  model: str = "pixellink",
-                 memplan: bool = True,
                  activation_budget_bytes: Optional[int] = None,
                  engine_cache_bytes: int = 0,
                  config: Optional[Any] = None,
@@ -136,7 +135,7 @@ class STDService:
         """``config`` is the base ``STDConfig`` every bucket's model is
         built from (``dataclasses.replace`` sets the plane and the
         precision axis); without one the base is the reduced VGG-16
-        serving model at ``width``/``mode``/``memplan``, which a config
+        serving model at ``width``/``mode``, which a config
         carries itself (so they are refused beside one).  ``device`` pins
         the SingleDevice engines and their parameters to one device, and
         with them each batch's inputs (one replica per chip behind
@@ -149,10 +148,9 @@ class STDService:
 
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
-        if config is not None and (width, mode, memplan) != (
-                0.25, "optimized", True):
-            raise ValueError("width/mode/memplan come from config when one "
-                             "is given; set them on the STDConfig")
+        if config is not None and (width, mode) != (0.25, "optimized"):
+            raise ValueError("width/mode come from config when one is "
+                             "given; set them on the STDConfig")
         if postprocess not in ("host", "device"):
             raise ValueError(
                 f"postprocess must be 'host' or 'device', got {postprocess!r}"
@@ -208,10 +206,9 @@ class STDService:
         # may batch above the fixed max_batch.  None = fixed max_batch.
         base = config if config is not None else STDConfig(
             backbone="vgg16", width=width, merge_ch=(16, 16, 8),
-            mode=mode, memplan=memplan,
+            mode=mode,
         )
         self.config = base
-        self.memplan_enabled = bool(base.memplan)
         self.activation_budget_bytes = activation_budget_bytes
         self._bucket_caps: Dict[Tuple[int, int], int] = {}
         # engines this service has dispatched to (their kernel words are
@@ -297,7 +294,7 @@ class STDService:
         footprints (core.memplan peak bytes) fit, rounded to the plan
         batch multiple; without one it is the fixed max_batch.  Cached —
         MicroBatcher calls this under its scheduler lock."""
-        if self.activation_budget_bytes is None or not self.memplan_enabled:
+        if self.activation_budget_bytes is None:
             return self.max_batch
         hw = tuple(hw)
         cap = self._bucket_caps.get(hw)
@@ -420,7 +417,7 @@ class STDService:
             self._built.add((hw, b, plan))
             engine = self.factory.model(hw, self.precision,
                                         self.model_name).engine
-            for name, n in engine.kernel_words(params).items():
+            for name, n in engine.kernel_words().items():
                 self.book.set_gauge(name, n)
         with span("std.dispatch.call", book=self.book, series=dict(
                 hw=hw, batch=b, kind=kind, stage="dispatch",

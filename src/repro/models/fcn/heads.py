@@ -447,7 +447,7 @@ class DetectionModel:
 
     ``cfg`` is duck-typed to the STDConfig fields (backbone, width,
     image_size, merge_ch, upsample_mode, mode, bfp, storage_fp16,
-    use_pallas; ``memplan`` is optional and defaults True)."""
+    use_pallas)."""
 
     def __init__(self, cfg, head: DetectionHead):
         self.cfg = cfg
@@ -467,7 +467,6 @@ class DetectionModel:
             bfp=cfg.bfp,
             storage_dtype=jnp.float16 if cfg.storage_fp16 else jnp.float32,
             use_pallas=cfg.use_pallas,
-            memplan=getattr(cfg, "memplan", True),
         )
 
     def init_params(self, key):

@@ -35,9 +35,6 @@ class STDConfig:
     storage_fp16: bool = True                    # paper's data-pool format
     use_pallas: bool = False                     # Pallas kernels in the
                                                  # optimized datapath
-    memplan: bool = True                         # static memory plan
-                                                 # (core.memplan): fusion
-                                                 # facts + drop-at-last-use
 
 
 class PixelLinkModel(DetectionModel):

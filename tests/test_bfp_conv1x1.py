@@ -3,8 +3,8 @@ on the Pallas path: the 1x1s on the fused kernel (one launch per word:
 in-kernel activation quantization, load-time weight matrices, bias and
 ReLU in the flush), the others with their activation round trip in one
 pass and their load-time kernels as they are; against the jnp round-trip
-path; raw weights normalized in the call; the kernel-word counters.
-Pallas runs interpreted on the CPU."""
+path; raw weights refused; the kernel-word counters.  Pallas runs
+interpreted on the CPU."""
 import re
 
 import jax
@@ -103,15 +103,13 @@ def test_fused_path_matches_roundtrip_path(setup):
             np.asarray(want[key], np.float32), atol=1e-2)
 
 
-def test_raw_weights_take_the_fallback_with_the_same_answer(setup):
-    """The normalized weights handed over raw (each conv kernel under
-    ``w``, unmarked) are normalized again in the call, which trunc
-    rounding leaves unchanged, and run the same kernels: the same maps
-    bit for bit."""
-    got = setup["run"](setup["fused"], unmarked(setup["normed"]))
-    for key in ("score", "links"):
-        np.testing.assert_array_equal(np.asarray(got[key]),
-                                      np.asarray(setup["out"][key]))
+def test_raw_weights_are_refused(setup):
+    """Raw weights, or the normalized ones handed over unmarked (each
+    conv kernel under ``w``), are refused when the engine is traced:
+    nothing quantizes a conv's weights in the call."""
+    for params in (setup["raw"], unmarked(setup["normed"])):
+        with pytest.raises(ValueError, match="normalize_weights"):
+            jax.eval_shape(setup["fused"].apply, params, setup["x"])
 
 
 def n_rows(model):
@@ -126,22 +124,11 @@ def n_rows(model):
 
 def test_counter_reads_fused_or_fallback_words(setup):
     model = setup["fused"]
-    fused, n, rows = model.engine, n_1x1(model), n_rows(model)
-    assert fused.kernel_words(setup["normed"]) == {
-        "bfp1x1_fused_words": n, "bfp1x1_fallback_words": 0,
-        "bfp_rows_words": rows, "bfp_incall_weight_words": 0}
-    for params in (setup["raw"], unmarked(setup["normed"])):
-        assert fused.kernel_words(params) == {
-            "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": n,
-            "bfp_rows_words": rows,
-            "bfp_incall_weight_words": n_other(model)}
+    assert model.engine.kernel_words() == {
+        "bfp1x1_fused_words": n_1x1(model), "bfp_rows_words": n_rows(model)}
     # without the Pallas kernels every conv takes the jnp round trip
-    plain = setup["plain"].engine
-    assert plain.kernel_words(setup["normed"]) == {
-        "bfp1x1_fused_words": 0, "bfp1x1_fallback_words": 0,
-        "bfp_rows_words": 0, "bfp_incall_weight_words": 0}
-    assert plain.kernel_words(setup["raw"])[
-        "bfp_incall_weight_words"] == n + n_other(model)
+    assert setup["plain"].engine.kernel_words() == {
+        "bfp1x1_fused_words": 0, "bfp_rows_words": 0}
 
 
 def test_rows_kernel_runs_under_the_roundtrip_scope(setup):
@@ -170,6 +157,5 @@ def test_service_books_the_counter_at_engine_build(backbone):
     svc.infer_labels(np.zeros((1, 32, 32, 3), np.float32), [(32, 32)])
     snap = svc.metrics_snapshot()
     assert snap["std_bfp1x1_fused_words"] == n_1x1(model)
-    assert snap["std_bfp1x1_fallback_words"] == 0
     assert snap["std_bfp_rows_words"] == n_rows(model)
-    assert snap["std_bfp_incall_weight_words"] == 0
+    assert not any("fallback" in k or "incall" in k for k in snap)

@@ -98,11 +98,10 @@ def test_device_pin_refuses_mesh_plans():
                    plan=DataParallel(mesh))
 
 
-@pytest.mark.parametrize("kw", [{"width": 0.5}, {"mode": "reference"},
-                                {"memplan": False}])
+@pytest.mark.parametrize("kw", [{"width": 0.5}, {"mode": "reference"}])
 def test_config_refuses_its_own_fields_as_kwargs(kw):
-    """A base STDConfig carries width, mode and memplan; passing one of
-    them beside it is refused instead of silently ignored."""
+    """A base STDConfig carries width and mode; passing one of them
+    beside it is refused instead of silently ignored."""
     from repro.configs.pixellink_std import SMOKE
     from repro.launch.serve import STDService
 

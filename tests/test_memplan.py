@@ -6,15 +6,17 @@ engine-memory metrics export, and the mode-aware upsample FLOP
 accounting in the cost model (core/rowband.program_band_costs)."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import fuse
+from repro.core import FCNEngine, fuse
 from repro.core.assembler import Assembler, LayerSpec, STORAGE_BYTES
 from repro.core.memplan import (
     _END,
     MemPlan,
     admissible_batch,
+    identity_plan,
     optimize_program,
     plan_disassembly,
     plan_program,
@@ -132,6 +134,22 @@ class TestElimination:
         assert plan.dead_words == (1,)
         assert plan.schedule == (0, 2)
         assert 1 not in plan.words
+
+    def test_default_engine_runs_without_dead_word_weights(self):
+        """An engine built with defaults runs its plan: the dead word is
+        never traced, so it needs no weights, and the output equals the
+        identity-plan run's, which runs every word."""
+        p = self.dead_branch_program()
+        eng = FCNEngine(p)
+        params = eng.init_params(jax.random.PRNGKey(0))
+        x = jax.random.normal(jax.random.PRNGKey(1), (1, *HW, 3))
+        live = {k: v for k, v in params.items() if k != "dead"}
+        got = eng(live, x)["c2"]
+        eng.memplan = identity_plan(p, eng.memplan.dtype_bytes)
+        with pytest.raises(KeyError):
+            eng(live, x)
+        want = eng(params, x)["c2"]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_optimize_program_removes_and_remaps(self):
         p = self.dead_branch_program()

@@ -143,40 +143,41 @@ class TestMemplanBoxParity:
     @pytest.mark.parametrize("name", sorted(MODEL_ZOO))
     @pytest.mark.parametrize("hw", [(64, 64), (96, 96)])
     def test_planned_engine_boxes_identical(self, name, hw):
-        """Property over the bucket grid: the memplan-scheduled engine
-        (fusion facts from the plan, buffers dropped at last use) and
-        the unplanned engine must be BOX-IDENTICAL — same weights, same
-        maps, same reference decode.  Maps are compared bitwise: the
-        plan may only reorder bookkeeping, never arithmetic."""
-        builds = {}
-        for on in (False, True):
-            m = DetectionModel(
-                STDConfig(name=f"{name}_vgg16", backbone="vgg16",
-                          width=0.125, image_size=hw,
-                          merge_ch=(16, 16, 8), mode="optimized",
-                          storage_fp16=False, memplan=on),
-                build_head(name),
-            )
-            params = m.init_params(jax.random.PRNGKey(0))
-            x = jax.random.uniform(jax.random.PRNGKey(5), (1, *hw, 3))
-            builds[on] = (m, m.apply(params, x))
-        m_on, maps_on = builds[True]
-        m_off, maps_off = builds[False]
-        assert m_on.engine.memplan is not None
-        assert m_off.engine.memplan is None
+        """Property over the bucket grid: the engine on its memory plan
+        (buffers dropped at last use) and the same engine on the
+        identity plan (every word run, everything kept) must be
+        BOX-IDENTICAL — same weights, same maps, same reference decode.
+        Maps are compared bitwise: the plan may only reorder
+        bookkeeping, never arithmetic."""
+        from repro.core.memplan import identity_plan
+
+        m = DetectionModel(
+            STDConfig(name=f"{name}_vgg16", backbone="vgg16",
+                      width=0.125, image_size=hw,
+                      merge_ch=(16, 16, 8), mode="optimized",
+                      storage_fp16=False),
+            build_head(name),
+        )
+        params = m.init_params(jax.random.PRNGKey(0))
+        x = jax.random.uniform(jax.random.PRNGKey(5), (1, *hw, 3))
+        planned = m.engine.memplan
+        assert planned.reduction > 0
+        maps_on = m.apply(params, x)
+        m.engine.memplan = identity_plan(m.program, planned.dtype_bytes)
+        maps_off = m.apply(params, x)
         for k in maps_off:
             assert np.array_equal(np.asarray(maps_off[k]),
                                   np.asarray(maps_on[k])), k
         valid = (hw[0], hw[1] - 8)
-        boxes = {
-            on: sorted(b["box"] for b in m.head.reference_decode(
+
+        def boxes(maps):
+            return sorted(b["box"] for b in m.head.reference_decode(
                 {k: np.asarray(v[0]) for k, v in maps.items()
                  if k != "logits"},
                 valid,
             ))
-            for on, (m, maps) in builds.items()
-        }
-        assert boxes[True] == boxes[False]
+
+        assert boxes(maps_on) == boxes(maps_off)
 
 
 class TestEngineLRUModelAxis:

@@ -1,8 +1,8 @@
 """Precision-mode serving tests: the (bucket, batch, plan, precision)
 engine identity, the bfp-vs-f32 accuracy-parity gate, and the engine
 state/bootstrap bugfix regressions that rode along (concurrent
-transposed tracing, in-call BFP weight quantization, backend-derived
-Pallas interpret default)."""
+transposed tracing, BFP engines refusing unquantized weights,
+backend-derived Pallas interpret default)."""
 import inspect
 import threading
 
@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import Assembler, BFPConfig, FCNEngine, LayerSpec
+from repro.core.interpreter import KERNEL, MATRIX
 
 
 def tiny_program(hw=(16, 16), *, bn=False):
@@ -151,9 +152,9 @@ class TestConcurrentTranspose:
 class TestBFPWeightQuantization:
     """Bugfix regression: with ``bfp`` set, ``_conv`` used to quantize
     activations but silently run UN-quantized f32 weights unless the
-    caller remembered ``normalize_weights()`` first.  Weights now
-    quantize in-call (idempotent trunc rounding makes pre-normalized
-    weights pass through unchanged)."""
+    caller remembered ``normalize_weights()`` first.  A BFP engine now
+    reads a conv's weights only in their load-time form and refuses raw
+    ones at trace time."""
 
     def setup_method(self, _):
         self.prog = tiny_program(bn=False)     # no BN: normalize_weights
@@ -161,21 +162,30 @@ class TestBFPWeightQuantization:
                                                # weight roundtrip
         self.x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 16, 3))
 
-    def test_raw_params_equal_normalized_params(self):
+    @pytest.mark.parametrize("name,key", [("cc", MATRIX), ("c2", KERNEL)],
+                             ids=["conv1x1", "conv3x3"])
+    def test_raw_params_are_refused(self, name, key):
+        """One word's normalized weights handed back raw (under ``w``)
+        are refused when the engine is traced, naming the key it reads
+        and normalize_weights."""
         eng = FCNEngine(self.prog, bfp=BFPConfig(mantissa_bits=6))
-        params = eng.init_params(jax.random.PRNGKey(1))
-        a = eng(params, self.x)["sg"]                       # raw entry
-        b = eng(eng.normalize_weights(params), self.x)["sg"]  # normalized
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        params = eng.normalize_weights(eng.init_params(jax.random.PRNGKey(1)))
+        p = dict(params[name])
+        w = p.pop(key)
+        p["w"] = w[None, None] if key == MATRIX else w
+        params[name] = p
+        with pytest.raises(ValueError, match=f"'{key}'.*normalize_weights"):
+            jax.eval_shape(eng, params, self.x)
 
     def test_raw_params_differ_from_f32(self):
-        """The in-call weight roundtrip must actually bite: a coarse
-        mantissa visibly moves the output vs the f32 engine."""
+        """The load-time weight roundtrip must actually bite: on the same
+        weights a coarse mantissa visibly moves the output vs the f32
+        engine."""
         eng_f = FCNEngine(self.prog)
         eng_b = FCNEngine(self.prog, bfp=BFPConfig(mantissa_bits=6))
         params = eng_f.init_params(jax.random.PRNGKey(1))
         a = eng_f(params, self.x)["sg"]
-        b = eng_b(params, self.x)["sg"]
+        b = eng_b(eng_b.normalize_weights(params), self.x)["sg"]
         assert float(jnp.max(jnp.abs(a - b))) > 0.0
 
 
